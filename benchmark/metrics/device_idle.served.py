@@ -1,0 +1,6 @@
+"""Share of the traced seconds of served traffic in which no operation ran on the chip."""
+
+
+def read(rec):
+    t = rec["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
