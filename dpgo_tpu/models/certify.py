@@ -380,7 +380,8 @@ def f64_recheck(f64_solve, tol: float):
 
     Returns ``(certified, decidable, lam_f64, vec64)``.
     """
-    lam_f64, vec64, resid = f64_solve(0.25 * tol)
+    with obs.trace.span("cert.f64"):
+        lam_f64, vec64, resid = f64_solve(0.25 * tol)
     certified = lam_f64 - resid >= -tol
     decidable = certified or (lam_f64 + resid < -tol)
     return bool(certified), bool(decidable), lam_f64, vec64
@@ -731,7 +732,8 @@ def lambda_min_f64_shift_invert(X64, edges: EdgeSet, tol_cert: float,
     from scipy.sparse.linalg import ArpackNoConvergence, eigsh
 
     n, r, dh = np.asarray(X64).shape
-    S = sparse_certificate(X64, edges)
+    with obs.trace.span("cert.f64.assemble"):
+        S = sparse_certificate(X64, edges)
 
     def pair(vals, vecs):
         idx = int(np.argmin(vals))
@@ -768,7 +770,8 @@ def lambda_min_f64_shift_invert(X64, edges: EdgeSet, tol_cert: float,
     # unconverged; an inconclusive pass (all Ritz >= -tol) falls through
     # to shift-invert.
     try:
-        vals, vecs = eigsh(S, k=4, which="SA", maxiter=60, tol=1e-7)
+        with obs.trace.span("cert.f64.sa"):
+            vals, vecs = eigsh(S, k=4, which="SA", maxiter=60, tol=1e-7)
         lam_sa, v_sa, r_sa = pair(vals, vecs)
     except ArpackNoConvergence as e:
         lam_sa = v_sa = r_sa = None
@@ -816,9 +819,10 @@ def lambda_min_f64_shift_invert(X64, edges: EdgeSet, tol_cert: float,
     SYc = S @ Yc
     defl_ok = float(np.linalg.norm(SYc, axis=0).max()) <= 0.1 * tol_cert
     try:
-        vals_l, vecs_l = _lobpcg(S, V0, Y=Yc, largest=False,
-                                 maxiter=300, tol=min(1e-8, 0.1 * tol_cert),
-                                 verbosityLevel=0)
+        with obs.trace.span("cert.f64.lobpcg"):
+            vals_l, vecs_l = _lobpcg(S, V0, Y=Yc, largest=False, maxiter=300,
+                                     tol=min(1e-8, 0.1 * tol_cert),
+                                     verbosityLevel=0)
         lam_l, v_l, r_l = pair(vals_l, vecs_l)
         rq_l = float(v_l @ (S @ v_l))  # explicit RQ of the unit vector
         lam_l_full = min(lam_l, 0.0)  # gauge zeros complete the spectrum
@@ -867,8 +871,9 @@ def lambda_min_f64_shift_invert(X64, edges: EdgeSet, tol_cert: float,
         big = float(np.abs(S).sum(axis=1).max())
         return 0.0, None, big
     try:
-        vals, vecs = eigsh(S, k=k, sigma=-tol_cert, which="LM",
-                           maxiter=maxiter, tol=1e-10)
+        with obs.trace.span("cert.f64.shift_invert"):
+            vals, vecs = eigsh(S, k=k, sigma=-tol_cert, which="LM",
+                               maxiter=maxiter, tol=1e-10)
     except ArpackNoConvergence as e:
         vals, vecs = e.eigenvalues, e.eigenvectors
         if vals is None or not len(vals):
@@ -981,14 +986,13 @@ def lambda_min_f64(X64, edges: EdgeSet, warm=None, num_probe: int = 4,
     # shift-invert path above (which supersedes deflation — the sparse
     # LU separates the zero cluster structurally); deflation remains for
     # matrix-free use where assembling S is not an option.
+    Yc = None
     if deflate:
         Yc = np.stack([np.asarray(X64[:, a, :], np.float64).reshape(n * dh)
                        for a in range(r)], axis=1)
         Yc, _ = np.linalg.qr(Yc)
+    with obs.trace.span("cert.f64.lobpcg"):
         vals, vecs = lobpcg(op, V0, Y=Yc, largest=False, maxiter=maxiter,
-                            tol=tol, verbosityLevel=0)
-    else:
-        vals, vecs = lobpcg(op, V0, largest=False, maxiter=maxiter,
                             tol=tol, verbosityLevel=0)
     i = int(np.argmin(vals))
     lam_min, v = float(vals[i]), vecs[:, i]

@@ -1848,17 +1848,18 @@ def _epilogue_certificate(fin: dict, edges_g: EdgeSet, params, dtype):
 
     certify_mode = getattr(params, "certify_mode", "off")
     eta = float(getattr(params, "certify_eta", 1e-5))
-    eg = edges_g._replace(weight=jnp.asarray(fin["w_glob"]))
-    if certify_mode == "host":
-        return certify_mod.certify_solution(jnp.asarray(fin["Xg"]), eg,
-                                            eta=eta)
-    pay = fin["cert"]
-    tol = eta * float(pay["wscale"])
-    f64_solve = certify_mod.host_f64_solve(fin["Xg"], eg, tol,
-                                           warm=pay["direction"])
-    return certify_mod.decide_device_certificate(
-        pay, eta, float(jnp.finfo(jnp.dtype(dtype)).eps),
-        f64_solve=f64_solve)
+    with trace.span("cert.decide"):
+        eg = edges_g._replace(weight=jnp.asarray(fin["w_glob"]))
+        if certify_mode == "host":
+            return certify_mod.certify_solution(jnp.asarray(fin["Xg"]), eg,
+                                                eta=eta)
+        pay = fin["cert"]
+        tol = eta * float(pay["wscale"])
+        f64_solve = certify_mod.host_f64_solve(fin["Xg"], eg, tol,
+                                               warm=pay["direction"])
+        return certify_mod.decide_device_certificate(
+            pay, eta, float(jnp.finfo(jnp.dtype(dtype)).eps),
+            f64_solve=f64_solve)
 
 
 def run_rbcd(
@@ -1948,7 +1949,6 @@ def run_rbcd(
             "verdict_every=K to use them")
     n_total = part.meas_global.num_poses
     num_meas = len(part.meas_global)
-    edges_g = edge_set_from_measurements(part.meas_global, dtype=dtype)
 
     # Telemetry (dpgo_tpu.obs): resolved ONCE per solve.  When off, the
     # eval program below is byte-identical to the uninstrumented driver —
@@ -1959,11 +1959,13 @@ def run_rbcd(
     obs_run = obs.get_run()
     telemetry = obs_run is not None
 
-    metrics_body = metrics_body_factory(telemetry) \
-        if metrics_body_factory is not None else None
-    central_metrics = jax.jit(metrics_body) if metrics_body is not None \
-        else _make_central_metrics(graph, edges_g, n_total, num_meas,
-                                   telemetry)
+    with trace.span("rbcd.programs"):
+        edges_g = edge_set_from_measurements(part.meas_global, dtype=dtype)
+        metrics_body = metrics_body_factory(telemetry) \
+            if metrics_body_factory is not None else None
+        central_metrics = jax.jit(metrics_body) if metrics_body is not None \
+            else _make_central_metrics(graph, edges_g, n_total, num_meas,
+                                       telemetry)
 
     robust_on = params is not None and \
         params.robust.cost_type != RobustCostType.L2
@@ -2039,10 +2041,6 @@ def run_rbcd(
         h_round = obs_run.histogram(
             "round_latency_seconds",
             "wall-clock per RBCD round at phase boundaries", unit="s")
-        g_agent_lat = obs_run.gauge(
-            "agent_round_latency_seconds",
-            "per-agent round latency (lockstep rounds: the eval-window "
-            "wall-clock over rounds, identical across agents)", unit="s")
         g_agent_rel = obs_run.gauge("agent_rel_change",
                                     "per-agent iterate relative change")
         if robust_on:
@@ -2068,7 +2066,6 @@ def run_rbcd(
         c_evals.inc()
         h_round.observe(per_round)
         for a in range(rel.shape[0]):
-            g_agent_lat.set(per_round, agent=a)
             g_agent_rel.set(float(rel[a]), agent=a)
         ev = {"iteration": it_ev, "round_latency_s": per_round,
               # rel is a host-side row of an already-materialized
@@ -2143,19 +2140,14 @@ def run_rbcd(
             if it < max_iters:
                 uw, rs, end = _bounds(it, num_weight_updates)
                 spec = (segment(state, end - it, uw, rs), end, uw)
-            if telemetry:
-                t_rb_m, t_rb_w = time.monotonic(), time.time()
             # THE sanctioned readback seam: the one stacked device->host
-            # fetch per eval.  dpgolint: disable=DPG003 -- sanctioned seam
-            vec = _host_fetch(fut)
+            # fetch per eval.  Its span shows how much of the round-trip
+            # stayed hidden behind the speculative segment.
+            with trace.span("rbcd.fetch", phase="eval",
+                            labels={"what": "eval"}, iteration=it):
+                # dpgolint: disable=DPG003 -- sanctioned seam
+                vec = _host_fetch(fut)
             host_fetches += 1
-            if telemetry:
-                # The eval readback span: the device->host fetch the pipelined
-                # driver hides behind the speculative segment — its duration on
-                # the timeline shows how much of the round-trip stayed hidden.
-                trace.emit_span(obs_run, "eval_readback", t_rb_m, t_rb_w,
-                                time.monotonic() - t_rb_m, phase="eval",
-                                iteration=it)
             f, gn, consensus = vec[:3]
             cost_hist.append(float(f))
             gn_hist.append(float(gn))
@@ -2190,8 +2182,9 @@ def run_rbcd(
         # THE terminal blocking read (epilogue + certificate payload) —
         # paid once per solve, excluded from the in-loop sync-rate metric
         # like the lazy finalize it replaces.
-        # dpgolint: disable=DPG003 -- sanctioned terminal epilogue fetch
-        fin = _host_fetch(fin)
+        with trace.span("rbcd.fetch", labels={"what": "epilogue"}):
+            # dpgolint: disable=DPG003 -- sanctioned terminal epilogue fetch
+            fin = _host_fetch(fin)
         certificate = _epilogue_certificate(fin, edges_g, params, dtype)
     T, w_glob = fin["T"], fin["w_glob"]
     if telemetry:
@@ -2249,33 +2242,20 @@ def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
             f"verdict_every={verdict_every} must be a positive multiple "
             f"of eval_every={eval_every}")
     max_evals = -(-max_iters // eval_every)
-    verdict_step = make_verdict_program(
-        graph, edges_g, n_total, num_meas, telemetry,
-        grad_norm_tol=grad_norm_tol,
-        robust_params=params.robust if robust_on else None,
-        max_evals=max_evals,
-        health_cfg=health_mon.config if health_mon is not None else None,
-        metrics_body=metrics_body)
-    vs0 = init_verdict_state(max_evals, meta.num_robots, dtype, telemetry)
     certify_mode = getattr(params, "certify_mode", "off") \
         if params is not None else "off"
-    epilogue = make_terminal_epilogue(graph, edges_g, n_total, num_meas,
-                                      meta, certify_mode=certify_mode)
-    if obs_run is not None:
-        # Compile accounting (ISSUE 16): the verdict program and the
-        # terminal epilogue report their cost/memory analysis and the
-        # bytes-per-flop roofline ratio through the same AOT probe as
-        # the serve cache — one compile per program either way, and any
-        # probe failure falls back to the plain jit callables.
-        from ..obs import devprof as _devprof
-
-        _plane = "sharded" if metrics_body is not None else "solve"
-        verdict_step = _devprof.profiled_program(
-            obs_run, verdict_step, key=f"verdict/k{verdict_every}",
-            label="verdict_step", plane=_plane)
-        epilogue = _devprof.profiled_program(
-            obs_run, epilogue, key="epilogue/terminal",
-            label="terminal_epilogue", plane=_plane)
+    with trace.span("rbcd.programs"):
+        verdict_step = make_verdict_program(
+            graph, edges_g, n_total, num_meas, telemetry,
+            grad_norm_tol=grad_norm_tol,
+            robust_params=params.robust if robust_on else None,
+            max_evals=max_evals,
+            health_cfg=health_mon.config if health_mon is not None else None,
+            metrics_body=metrics_body)
+        vs0 = init_verdict_state(max_evals, meta.num_robots, dtype,
+                                 telemetry)
+        epilogue = make_terminal_epilogue(graph, edges_g, n_total, num_meas,
+                                          meta, certify_mode=certify_mode)
 
     eval_its: list[int] = []
     fetches = 0
@@ -2283,16 +2263,17 @@ def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
     def advance(st, it, nwu, vs, target):
         """Enqueue segments + fused verdict evals up to ``target`` (no
         host synchronization — everything stays in flight)."""
-        while it < target:
-            ev_t = min(((it // eval_every) + 1) * eval_every, target)
-            while it < ev_t:
-                uw, rs, end = bounds(it, nwu)
-                nwu += int(uw)
-                st = segment(st, end - it, uw, rs)
-                it = end
-            vs = verdict_step(st.X, st.weights, st.ready, st.mu,
-                              st.rel_change, st.iteration, vs)
-            eval_its.append(it)
+        with trace.span("rbcd.enqueue", labels={"what": "rounds"}):
+            while it < target:
+                ev_t = min(((it // eval_every) + 1) * eval_every, target)
+                while it < ev_t:
+                    uw, rs, end = bounds(it, nwu)
+                    nwu += int(uw)
+                    st = segment(st, end - it, uw, rs)
+                    it = end
+                vs = verdict_step(st.X, st.weights, st.ready, st.mu,
+                                  st.rel_change, st.iteration, vs)
+                eval_its.append(it)
         return st, it, nwu, vs
 
     t_solve0 = t_window = time.perf_counter()
@@ -2318,8 +2299,9 @@ def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
             # THE verdict readback: one packed int32 per K rounds (from
             # the pre-speculation state, so it never waits on the
             # speculative work).
-            # dpgolint: disable=DPG003 -- sanctioned verdict-word fetch
-            word = int(_host_fetch(vs_pre.word))
+            with trace.span("rbcd.fetch", labels={"what": "word"}):
+                # dpgolint: disable=DPG003 -- sanctioned verdict-word fetch
+                word = int(_host_fetch(vs_pre.word))
             fetches += 1
             status = word & 7
             terminal = status != VERDICT_RUNNING or it_pre >= max_iters
@@ -2335,8 +2317,9 @@ def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
                 # telemetry/health/recorder consumers see — recurring
                 # (counted) with telemetry on; at termination the rows
                 # ride the fused epilogue fetch below instead.
-                # dpgolint: disable=DPG003 -- sanctioned lazy history fetch
-                hist_rows = _host_fetch(vs_pre.hist)
+                with trace.span("rbcd.fetch", labels={"what": "history"}):
+                    # dpgolint: disable=DPG003 -- sanctioned lazy history fetch
+                    hist_rows = _host_fetch(vs_pre.hist)
                 fetches += 1
             if terminal:
                 # THE terminal blocking read: rounding/anchoring, the
@@ -2346,12 +2329,15 @@ def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
                 # history leg replaces the recurring telemetry fetch at
                 # this boundary (same count); everything else replaced
                 # the old separate tail fetch + lazy finalize.
-                # dpgolint: disable=DPG003 -- sanctioned terminal epilogue fetch
-                fin = _host_fetch(epilogue(
-                    state_pre.X, state_pre.weights,
-                    {"hist": vs_pre.hist,
-                     "tail": jnp.stack([vs_pre.term_eval,
-                                        vs_pre.term_it])}))
+                with trace.span("rbcd.enqueue", labels={"what": "epilogue"}):
+                    fin = epilogue(
+                        state_pre.X, state_pre.weights,
+                        {"hist": vs_pre.hist,
+                         "tail": jnp.stack([vs_pre.term_eval,
+                                            vs_pre.term_it])})
+                with trace.span("rbcd.fetch", labels={"what": "epilogue"}):
+                    # dpgolint: disable=DPG003 -- sanctioned terminal epilogue fetch
+                    fin = _host_fetch(fin)
                 hist_rows = fin["hist"]
                 fetches += int(telemetry)
                 term_eval, term_it = int(fin["tail"][0]), int(fin["tail"][1])
@@ -2480,11 +2466,17 @@ def prepare_problem(
     problem to its shape bucket first and initializes on the padded
     problem, so the compiled init program is shared across the bucket."""
     params = params or AgentParams(d=meas.d, r=5, num_robots=num_robots)
-    part = part or partition_contiguous(meas, num_robots)
-    graph, meta = build_graph(part, params.r, dtype, pallas_sel=pallas_sel,
-                              sel_mode=resolved_sel_mode(params))
-    X0 = initial_state_for(init, part, meta, graph, params, dtype) \
-        if init is not None else None
+    with trace.span("rbcd.prepare"):
+        with trace.span("rbcd.partition"):
+            part = part or partition_contiguous(meas, num_robots)
+        with trace.span("rbcd.build_graph"):
+            graph, meta = build_graph(part, params.r, dtype,
+                                      pallas_sel=pallas_sel,
+                                      sel_mode=resolved_sel_mode(params))
+        # Host dispatch only: the init's device work runs on past it.
+        with trace.span("rbcd.init"):
+            X0 = initial_state_for(init, part, meta, graph, params, dtype) \
+                if init is not None else None
     return PreparedProblem(part=part, graph=graph, meta=meta, params=params,
                            dtype=dtype, X0=X0)
 
@@ -2504,12 +2496,10 @@ def dispatch_prepared(
     packed-word readback per K rounds — see ``run_rbcd``)."""
     params = prob.params
     max_iters = params.max_num_iters if max_iters is None else max_iters
-    if state is None:
-        if prob.X0 is None:
-            raise ValueError(
-                "prepared problem has no initial state — prepare with "
-                "init=... or pass state=")
-        state = init_state(prob.graph, prob.meta, prob.X0, params=params)
+    if state is None and prob.X0 is None:
+        raise ValueError(
+            "prepared problem has no initial state — prepare with "
+            "init=... or pass state=")
     graph, meta = prob.graph, prob.meta
     step = lambda s, uw, rs: rbcd_step(s, graph, meta, params,
                                        update_weights=uw, restart=rs)
@@ -2517,10 +2507,14 @@ def dispatch_prepared(
     seg = lambda s, k, uw, rs: rbcd_segment(s, graph, k, meta, params,
                                             first_update_weights=uw,
                                             first_restart=rs)
-    return run_rbcd(state, graph, meta, step, prob.part, max_iters,
-                    grad_norm_tol, eval_every, prob.dtype, params=params,
-                    multi_step=multi, segment=seg,
-                    verdict_every=verdict_every)
+    with trace.span("rbcd.dispatch"):
+        if state is None:
+            with trace.span("rbcd.programs"):
+                state = init_state(graph, meta, prob.X0, params=params)
+        return run_rbcd(state, graph, meta, step, prob.part, max_iters,
+                        grad_norm_tol, eval_every, prob.dtype, params=params,
+                        multi_step=multi, segment=seg,
+                        verdict_every=verdict_every)
 
 
 def solve_rbcd(

@@ -35,7 +35,22 @@ robustness change reports through:
   entries, and ``python -m dpgo_tpu.obs.timeline <run_dir>...`` merges
   per-robot streams (pairwise clock-offset estimation from the
   send/receive stamps riding heartbeats and traced frames) into a
-  Perfetto-loadable Chrome trace with cross-robot flow arrows.
+  Perfetto-loadable Chrome trace with cross-robot flow arrows.  A span
+  entered with ``with trace.span(name)`` also opens a
+  ``jax.profiler.TraceAnnotation`` named ``dpgo.<name>`` (on the
+  profiler trace's host plane, on the device ops' clock), and every
+  ended span observes ``span_seconds{span=<name>}``.  The solve path's
+  spans: ``rbcd.prepare`` (``rbcd.partition``, ``rbcd.build_graph``,
+  ``rbcd.init``), ``rbcd.dispatch`` (``rbcd.programs``,
+  ``rbcd.enqueue``, ``rbcd.fetch{what=word|history|epilogue|eval}``,
+  ``cert.decide``), and inside the certificate ``cert.f64``,
+  ``cert.f64.assemble``, ``cert.f64.sa``, ``cert.f64.lobpcg``,
+  ``cert.f64.shift_invert``.
+* Compile counters (``compile.py``) — JAX's trace / lower / build
+  durations and persistent-cache hits, forwarded from ``jax.monitoring``
+  as ``jax_compile_seconds_total{stage, program, span}`` and
+  ``jax_programs_total{source=cache|compiled, program}``; the ``span``
+  label is the innermost open span, the step that paid for the compile.
 * Numerical health (``health.py``) — in-band anomaly detectors fed by
   the scalars the driver already reads back (NaN/Inf sentinel,
   per-GNC-stage cost monotonicity, gradient-norm explosion, stall,

@@ -109,7 +109,7 @@ def aot_compile_profile(run, jitfn, args, kwargs, key: str, label: str,
     roofline ratio (arithmetic intensity's reciprocal: how memory-bound
     the program is).  ``phase``/``metric_prefix`` scope the event and
     metric names to the emitting plane (``serve`` for the executable
-    cache, ``solve``/``sharded`` via ``devprof.profiled_program``).
+    cache, ``sharded`` via ``devprof.profiled_program``).
     ``run`` is the caller's already-resolved ambient run — the caller's
     fence, like ``emit_span``."""
     t0 = time.monotonic()

@@ -170,8 +170,12 @@ def start_run(run_dir: str, run_id: str | None = None) -> TelemetryRun:
 
     Refuses to silently replace a live ambient run — two overlapping runs
     would interleave their instrumentation; scope with ``run_scope`` or
-    ``end_run()`` first."""
+    ``end_run()`` first.  The first call also registers the compile
+    counters' ``jax.monitoring`` listeners (``obs.compile``)."""
     global _current
+    from .compile import install as install_compile_counters
+
+    install_compile_counters()
     run = TelemetryRun(run_dir, run_id)
     with _lock:
         if _current is not None and not _current.closed:

@@ -1,11 +1,21 @@
-"""Lightweight distributed spans for the deployment plane.
+"""Lightweight spans: the deployment plane's distributed spans and the
+solve path's host phases.
 
 A *span* is one timed unit of work — an ``iterate`` step, a ``publish``,
-the blocking part of an overlapped exchange — emitted through the run's
-existing ``EventStream`` as a single ``span`` event at close:
+the blocking part of an overlapped exchange, a verdict-word fetch —
+emitted through the run's existing ``EventStream`` as a single ``span``
+event at close:
 
 ``{"event": "span", "name", "phase", "robot", "trace", "span",
-"parent"?, "t0_mono", "t0_wall", "dur_s", "link_*"?, **counters}``
+"parent"?, "t0_mono", "t0_wall", "dur_s", "link_*"?, **labels,
+**counters}``
+
+Every ended span also observes its duration into the run's
+``span_seconds{span=<name>, **labels}`` histogram, so a reader takes
+per-phase seconds over a window from two registry snapshots.  A span
+entered with ``with`` also opens a ``jax.profiler.TraceAnnotation`` named
+``dpgo.<name>``: under a profiler trace it sits on the host plane of the
+``.xplane.pb``, on the same clock as the device's ops.
 
 Ids are random 63-bit integers rendered as 16-hex-digit strings.  Spans
 nest through a thread-local stack (``with span(...)``): a span opened
@@ -26,10 +36,11 @@ one causal edge set across robots.
 
 Zero-overhead fence: every entry point resolves ``get_run()`` first and
 returns the no-op ``NULL_SPAN`` (or emits nothing) when telemetry is off
-— the same contract as the rest of ``dpgo_tpu.obs``
+— the same contract as the rest of ``dpgo_tpu.obs``.  The annotation and
+the histogram belong to the ``Span``, so with telemetry off neither exists
 (``tests/test_obs.py::test_telemetry_off_is_zero_overhead`` patches
-``Span.__init__`` and ``emit_span`` to throw and drives the instrumented
-paths with telemetry off).
+``Span.__init__``, ``emit_span``, ``TraceAnnotation`` and the registry to
+throw and drives the instrumented paths with telemetry off).
 """
 
 from __future__ import annotations
@@ -90,17 +101,20 @@ class Span:
     path by definition, which is what makes the zero-overhead test's
     ``Span.__init__``-throws patch a complete fence."""
 
-    __slots__ = ("run", "name", "phase", "robot", "trace_id", "span_id",
-                 "parent_id", "t0_mono", "t0_wall", "_counters", "_link",
-                 "_ended")
+    __slots__ = ("run", "name", "phase", "robot", "labels", "trace_id",
+                 "span_id", "parent_id", "t0_mono", "t0_wall", "_counters",
+                 "_link", "_ended", "_annotation")
 
     def __init__(self, run, name: str, phase: str | None = None,
                  robot: int | None = None, trace_id: int | None = None,
-                 parent_id: int | None = None, link=None):
+                 parent_id: int | None = None, link=None,
+                 labels: dict | None = None):
         self.run = run
         self.name = str(name)
         self.phase = phase
         self.robot = robot
+        self.labels = {str(k): str(v) for k, v in (labels or {}).items()}
+        self._annotation = None
         self.span_id = new_id()
         parent = current_span()
         if trace_id is None:
@@ -126,34 +140,46 @@ class Span:
         self._ended = True
         if counters:
             self._counters.update(counters)
+        dur = time.monotonic() - self.t0_mono
         fields = {"name": self.name, "trace": _hex(self.trace_id),
                   "span": _hex(self.span_id), "t0_mono": self.t0_mono,
-                  "t0_wall": self.t0_wall,
-                  "dur_s": time.monotonic() - self.t0_mono}
+                  "t0_wall": self.t0_wall, "dur_s": dur}
         if self.robot is not None:
             fields["robot"] = int(self.robot)
         if self.parent_id:
             fields["parent"] = _hex(self.parent_id)
         if self._link is not None:
             fields.update(link_fields(self._link))
+        fields.update(self.labels)
         fields.update(self._counters)
         self.run.events.emit("span", phase=self.phase, **fields)
+        _observe(self.run, self.name, dur, self.labels)
 
     # -- context manager (pushes onto the thread-local parent stack) --------
 
     def __enter__(self) -> "Span":
+        import jax
+
         stack = getattr(_tls, "stack", None)
         if stack is None:
             stack = _tls.stack = []
         stack.append(self)
+        self._annotation = jax.profiler.TraceAnnotation("dpgo." + self.name)
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
+        self._annotation.__exit__(exc_type, exc, tb)
         self.end(error=repr(exc)) if exc is not None else self.end()
         return False
+
+
+def _observe(run, name: str, dur_s: float, labels: dict) -> None:
+    run.histogram("span_seconds", "host seconds of each ended span",
+                  unit="s").observe(dur_s, span=name, **labels)
 
 
 class _NullSpan:
@@ -191,14 +217,16 @@ def start_span(name: str, phase: str | None = None,
 
 
 def span(name: str, phase: str | None = None, robot: int | None = None,
-         link=None, **counters):
+         link=None, labels: dict | None = None, **counters):
     """``with span("publish", phase="comms", robot=2): ...`` — a no-op
     context manager with telemetry off, a parent-stack-participating
-    ``Span`` otherwise."""
+    ``Span`` otherwise.  ``labels`` ride the event and label the span's
+    ``span_seconds`` series (keep them few-valued); ``counters`` ride the
+    event only."""
     run = get_run()
     if run is None:
         return NULL_SPAN
-    sp = Span(run, name, phase=phase, robot=robot, link=link)
+    sp = Span(run, name, phase=phase, robot=robot, link=link, labels=labels)
     if counters:
         sp.add(**counters)
     return sp
@@ -234,3 +262,4 @@ def emit_span(run, name: str, t0_mono: float, t0_wall: float, dur_s: float,
         fields.update(link_fields(link))
     fields.update(counters)
     run.events.emit("span", phase=phase, **fields)
+    _observe(run, str(name), float(dur_s), {})

@@ -8,9 +8,10 @@ equivalents here (SURVEY.md section 5):
 * ``trace(logdir)`` — context manager around ``jax.profiler`` capturing a
   device timeline (XLA op breakdown, HBM traffic) viewable in
   TensorBoard/Perfetto.  Works on CPU and TPU backends.
-* ``annotate(name)`` — named region that shows up inside the timeline
-  (wraps ``jax.profiler.TraceAnnotation``); use around driver phases
-  (exchange / solve / eval) when hunting dispatch gaps.
+* named regions inside the timeline are ``dpgo_tpu.obs.trace.span``:
+  with a telemetry run installed each span also opens a
+  ``jax.profiler.TraceAnnotation`` named ``dpgo.<name>``; the solve
+  path's phases (prepare, enqueue, fetch, certificate) already carry one.
 * ``RoundTimer`` — lightweight host-side per-phase wall-clock accumulator
   for driver loops: ``stop`` optionally blocks on a device value by
   materializing it.
@@ -46,13 +47,6 @@ def trace(logdir: str):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named timeline region: ``with profiling.annotate("exchange"): ...``"""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 class RoundTimer:

@@ -674,7 +674,7 @@ def test_sanctioned_verdict_fetches_stay_suppressed(monkeypatch):
     one suppression makes DPG003 fire at that site."""
     src = (REPO / "dpgo_tpu" / "models" / "rbcd.py").read_text()
     stripped = src.replace(
-        "            # dpgolint: disable=DPG003 -- sanctioned "
+        "                # dpgolint: disable=DPG003 -- sanctioned "
         "verdict-word fetch\n", "")
     assert stripped != src
     import tempfile

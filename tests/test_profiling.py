@@ -6,13 +6,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dpgo_tpu import obs
 from dpgo_tpu.utils import profiling
 
 
 def test_trace_writes_profile(tmp_path):
     logdir = str(tmp_path / "trace")
-    with profiling.trace(logdir):
-        with profiling.annotate("work"):
+    with obs.run_scope(str(tmp_path / "run")), profiling.trace(logdir):
+        with obs.trace.span("work"):
             x = jnp.ones((64, 64)) @ jnp.ones((64, 64))
             np.asarray(x)
     found = [os.path.join(r, f) for r, _d, fs in os.walk(logdir) for f in fs]
