@@ -1511,6 +1511,13 @@ def _central_metrics_body(graph: MultiAgentGraph, edges_g: EdgeSet,
     return central_metrics
 
 
+@partial(jax.jit, static_argnames=("n_total", "num_meas", "telemetry"))
+def _central_metrics_jit(graph, edges_g, Xa, weights, ready, mu, rel_change,
+                         *, n_total, num_meas, telemetry):
+    return _central_metrics_body(graph, edges_g, n_total, num_meas,
+                                 telemetry)(Xa, weights, ready, mu, rel_change)
+
+
 def _make_central_metrics(graph: MultiAgentGraph, edges_g: EdgeSet,
                           n_total: int, num_meas: int, telemetry: bool):
     """The jitted per-eval readback program of ``run_rbcd`` — one stacked
@@ -1518,9 +1525,19 @@ def _make_central_metrics(graph: MultiAgentGraph, edges_g: EdgeSet,
     fetch would be its own device->host round-trip).  Factored out so the
     flight recorder's replay evaluates the recorded trajectory through the
     byte-identical XLA program (bit-for-bit reproduction requires the same
-    compiled reduction order, not merely the same math)."""
-    return jax.jit(_central_metrics_body(graph, edges_g, n_total, num_meas,
-                                         telemetry))
+    compiled reduction order, not merely the same math).
+
+    The returned callable binds the problem and calls the module-level
+    ``_central_metrics_jit``, which takes ``graph``/``edges_g`` as
+    arguments: the executable is keyed on shapes and the static settings
+    only, so every problem of one padded shape shares it."""
+
+    def central_metrics(Xa, weights, ready, mu, rel_change):
+        return _central_metrics_jit(graph, edges_g, Xa, weights, ready, mu,
+                                    rel_change, n_total=n_total,
+                                    num_meas=num_meas, telemetry=telemetry)
+
+    return central_metrics
 
 
 # ---------------------------------------------------------------------------
@@ -1639,6 +1656,118 @@ def _device_gnc_stage(mu, mu0: float, step: float, kmax: int):
     return jnp.clip(k.astype(jnp.int32), 0, kmax)
 
 
+def _verdict_update(vec, mu, rel_change, iteration, vs: VerdictState,
+                    grad_norm_tol, *, gnc, health) -> VerdictState:
+    """THE verdict update of one eval: everything downstream of the
+    stacked metrics row ``vec`` (convergence test, health predicates,
+    latch, history append).  One pure function traced by both the
+    single-device program (``_verdict_step_jit``) and the sharded plane's
+    ``metrics_body=`` closure, so the word semantics cannot drift between
+    the two.  ``gnc`` is None or the static ``(mu0, step, kmax)``;
+    ``health`` the static thresholds of ``_verdict_statics``;
+    ``grad_norm_tol`` may be traced."""
+    spike_rtol, spike_atol, expl_factor, gn_floor, stall_window, \
+        stall_rtol = health
+    f, gn, consensus = vec[0], vec[1], vec[2]
+    if gnc is not None:
+        stage = _device_gnc_stage(mu, *gnc)
+    else:
+        stage = jnp.zeros((), jnp.int32)
+
+    # Per-stage baselines reset on stage transitions (the monitor's
+    # _new_stage); the stall anchor additionally seeds itself on the
+    # first finite cost.
+    fresh = stage != vs.stage
+    inf = jnp.asarray(jnp.inf, vec.dtype)
+    best = jnp.where(fresh, inf, vs.best_cost)
+    ming = jnp.where(fresh, inf, vs.min_gn)
+    seed = fresh | ~jnp.isfinite(vs.stall_anchor)
+    anchor = jnp.where(seed, f, vs.stall_anchor)
+    slen = jnp.where(seed, 0, vs.stall_len)
+    sfired = jnp.where(fresh, False, vs.stall_fired)
+
+    finite = jnp.isfinite(f) & jnp.isfinite(gn) \
+        & jnp.all(jnp.isfinite(rel_change))
+    # Judge against the PRE-update baselines (monitor order), and only
+    # on finite evals (the monitor early-returns on non-finite).
+    spike = finite & jnp.isfinite(best) \
+        & (f > best * (1.0 + spike_rtol) + spike_atol)
+    expl = finite & jnp.isfinite(ming) \
+        & (gn > expl_factor * jnp.maximum(ming, gn_floor))
+    if stall_window > 1:
+        slen = slen + 1
+        full = slen >= stall_window
+        stalled = finite & full & ~sfired \
+            & (anchor - f <= stall_rtol * jnp.abs(anchor))
+        sfired = sfired | stalled
+        anchor = jnp.where(full, f, anchor)
+        slen = jnp.where(full, 0, slen)
+    else:
+        stalled = jnp.zeros((), bool)
+
+    code = jnp.zeros((), jnp.int32)
+    code = jnp.maximum(code, jnp.where(spike, ANOMALY_COST_SPIKE, 0))
+    code = jnp.maximum(code, jnp.where(stalled, ANOMALY_STALL, 0))
+    code = jnp.maximum(code, jnp.where(expl, ANOMALY_GRAD_EXPLOSION, 0))
+    code = jnp.maximum(code, jnp.where(~finite, ANOMALY_NON_FINITE, 0))
+    anom = jnp.maximum((vs.word >> 3) & 7, code)
+
+    status_now = jnp.where(
+        gn < grad_norm_tol, VERDICT_GRAD_NORM,
+        jnp.where(consensus > 0, VERDICT_CONSENSUS,
+                  VERDICT_RUNNING)).astype(jnp.int32)
+    status = jnp.where(vs.term_eval >= 0, vs.word & 7, status_now)
+    first_term = (vs.term_eval < 0) & (status != VERDICT_RUNNING)
+    term_eval = jnp.where(first_term, vs.eval_idx, vs.term_eval)
+    term_it = jnp.where(first_term, iteration.astype(jnp.int32), vs.term_it)
+
+    best = jnp.where(finite, jnp.minimum(best, f), best)
+    ming = jnp.where(finite, jnp.minimum(ming, gn), ming)
+    hist = jax.lax.dynamic_update_slice(
+        vs.hist, vec[None, :].astype(vs.hist.dtype),
+        (vs.eval_idx, jnp.zeros((), vs.eval_idx.dtype)))
+    word = (status | (anom << 3) | (stage << 6)).astype(jnp.int32)
+    return VerdictState(word=word, eval_idx=vs.eval_idx + 1,
+                        term_eval=term_eval, term_it=term_it,
+                        best_cost=best, min_gn=ming, stage=stage,
+                        stall_anchor=anchor, stall_len=slen,
+                        stall_fired=sfired, hist=hist)
+
+
+def _verdict_statics(robust_params: RobustCostParams | None, health_cfg):
+    """The static settings of a verdict program as hashable Python
+    values: ``gnc`` (None, or the GNC ``(mu0, step, kmax)``) and
+    ``health`` (cost-spike rtol/atol, explosion factor, gradient floor,
+    stall window, stall rtol)."""
+    if health_cfg is None:
+        from ..obs.health import HealthConfig
+
+        health_cfg = HealthConfig()
+    health = (float(health_cfg.cost_spike_rtol),
+              float(health_cfg.cost_spike_atol),
+              float(health_cfg.grad_explosion_factor),
+              float(health_cfg.grad_floor),
+              int(health_cfg.stall_window),
+              float(health_cfg.stall_rtol))
+    gnc = None
+    if robust_params is not None:
+        gnc = (float(robust_params.gnc_init_mu),
+               float(robust_params.gnc_mu_step),
+               int(robust_params.gnc_max_iters))
+    return gnc, health
+
+
+@partial(jax.jit, static_argnames=("n_total", "num_meas", "telemetry", "gnc",
+                                   "health"))
+def _verdict_step_jit(graph, edges_g, Xa, weights, ready, mu, rel_change,
+                      iteration, vs, grad_norm_tol, *, n_total, num_meas,
+                      telemetry, gnc, health) -> VerdictState:
+    vec = _central_metrics_body(graph, edges_g, n_total, num_meas,
+                                telemetry)(Xa, weights, ready, mu, rel_change)
+    return _verdict_update(vec, mu, rel_change, iteration, vs, grad_norm_tol,
+                           gnc=gnc, health=health)
+
+
 def make_verdict_program(graph: MultiAgentGraph, edges_g: EdgeSet,
                          n_total: int, num_meas: int, telemetry: bool, *,
                          grad_norm_tol: float,
@@ -1665,103 +1794,44 @@ def make_verdict_program(graph: MultiAgentGraph, edges_g: EdgeSet,
     than eval boundaries in ``max_iters``.  ``health_cfg`` duck-types
     ``obs.health.HealthConfig`` (defaults used when None).
 
+    The returned ``verdict_step`` binds ``graph``/``edges_g`` and calls
+    the module-level ``_verdict_step_jit``, which takes the problem and
+    the gradient tolerance as arguments: the executable is keyed on
+    shapes and the static settings (telemetry, GNC constants, health
+    thresholds) only, so two solves of one padded shape share it and
+    neither traces nor lowers anything the other already built.
+
     ``metrics_body`` overrides the stacked-metrics subcomputation — THE
     reuse seam of the sharded plane: ``parallel.sharded`` traces the same
     row schema inside ``shard_map`` with its reductions as psums
     (``make_sharded_metrics_body``), and everything downstream of the row
-    (convergence test, health predicates, latch, history) is this one
-    shared program, so the verdict-word semantics cannot drift between
-    the single-device and mesh paths.  The override must match
-    ``_central_metrics_body``'s signature and row width."""
-    if health_cfg is None:
-        from ..obs.health import HealthConfig
-
-        health_cfg = HealthConfig()
-    body = metrics_body if metrics_body is not None else \
-        _central_metrics_body(graph, edges_g, n_total, num_meas, telemetry)
-    spike_rtol = float(health_cfg.cost_spike_rtol)
-    spike_atol = float(health_cfg.cost_spike_atol)
-    expl_factor = float(health_cfg.grad_explosion_factor)
-    gn_floor = float(health_cfg.grad_floor)
-    stall_window = int(health_cfg.stall_window)
-    stall_rtol = float(health_cfg.stall_rtol)
+    (convergence test, health predicates, latch, history) is the one
+    shared ``_verdict_update``, so the verdict-word semantics cannot drift
+    between the single-device and mesh paths.  The override must match
+    ``_central_metrics_body``'s signature and row width; its program is
+    a closure built per call, since the body closes over the mesh."""
     del max_evals  # sized into the VerdictState by init_verdict_state
-    if robust_params is not None:
-        gnc_mu0 = float(robust_params.gnc_init_mu)
-        gnc_step = float(robust_params.gnc_mu_step)
-        gnc_kmax = int(robust_params.gnc_max_iters)
+    gnc, health = _verdict_statics(robust_params, health_cfg)
 
-    @jax.jit
+    if metrics_body is not None:
+        @jax.jit
+        def verdict_step(Xa, weights, ready, mu, rel_change, iteration,
+                         vs: VerdictState) -> VerdictState:
+            return _verdict_update(
+                metrics_body(Xa, weights, ready, mu, rel_change), mu,
+                rel_change, iteration, vs, grad_norm_tol, gnc=gnc,
+                health=health)
+
+        return verdict_step
+
+    tol = jnp.asarray(grad_norm_tol)
+
     def verdict_step(Xa, weights, ready, mu, rel_change, iteration,
                      vs: VerdictState) -> VerdictState:
-        vec = body(Xa, weights, ready, mu, rel_change)
-        f, gn, consensus = vec[0], vec[1], vec[2]
-        if robust_params is not None:
-            stage = _device_gnc_stage(mu, gnc_mu0, gnc_step, gnc_kmax)
-        else:
-            stage = jnp.zeros((), jnp.int32)
-
-        # Per-stage baselines reset on stage transitions (the monitor's
-        # _new_stage); the stall anchor additionally seeds itself on the
-        # first finite cost.
-        fresh = stage != vs.stage
-        inf = jnp.asarray(jnp.inf, vec.dtype)
-        best = jnp.where(fresh, inf, vs.best_cost)
-        ming = jnp.where(fresh, inf, vs.min_gn)
-        seed = fresh | ~jnp.isfinite(vs.stall_anchor)
-        anchor = jnp.where(seed, f, vs.stall_anchor)
-        slen = jnp.where(seed, 0, vs.stall_len)
-        sfired = jnp.where(fresh, False, vs.stall_fired)
-
-        finite = jnp.isfinite(f) & jnp.isfinite(gn) \
-            & jnp.all(jnp.isfinite(rel_change))
-        # Judge against the PRE-update baselines (monitor order), and only
-        # on finite evals (the monitor early-returns on non-finite).
-        spike = finite & jnp.isfinite(best) \
-            & (f > best * (1.0 + spike_rtol) + spike_atol)
-        expl = finite & jnp.isfinite(ming) \
-            & (gn > expl_factor * jnp.maximum(ming, gn_floor))
-        if stall_window > 1:
-            slen = slen + 1
-            full = slen >= stall_window
-            stalled = finite & full & ~sfired \
-                & (anchor - f <= stall_rtol * jnp.abs(anchor))
-            sfired = sfired | stalled
-            anchor = jnp.where(full, f, anchor)
-            slen = jnp.where(full, 0, slen)
-        else:
-            stalled = jnp.zeros((), bool)
-
-        code = jnp.zeros((), jnp.int32)
-        code = jnp.maximum(code, jnp.where(spike, ANOMALY_COST_SPIKE, 0))
-        code = jnp.maximum(code, jnp.where(stalled, ANOMALY_STALL, 0))
-        code = jnp.maximum(code,
-                           jnp.where(expl, ANOMALY_GRAD_EXPLOSION, 0))
-        code = jnp.maximum(code,
-                           jnp.where(~finite, ANOMALY_NON_FINITE, 0))
-        anom = jnp.maximum((vs.word >> 3) & 7, code)
-
-        status_now = jnp.where(
-            gn < grad_norm_tol, VERDICT_GRAD_NORM,
-            jnp.where(consensus > 0, VERDICT_CONSENSUS,
-                      VERDICT_RUNNING)).astype(jnp.int32)
-        status = jnp.where(vs.term_eval >= 0, vs.word & 7, status_now)
-        first_term = (vs.term_eval < 0) & (status != VERDICT_RUNNING)
-        term_eval = jnp.where(first_term, vs.eval_idx, vs.term_eval)
-        term_it = jnp.where(first_term, iteration.astype(jnp.int32),
-                            vs.term_it)
-
-        best = jnp.where(finite, jnp.minimum(best, f), best)
-        ming = jnp.where(finite, jnp.minimum(ming, gn), ming)
-        hist = jax.lax.dynamic_update_slice(
-            vs.hist, vec[None, :].astype(vs.hist.dtype),
-            (vs.eval_idx, jnp.zeros((), vs.eval_idx.dtype)))
-        word = (status | (anom << 3) | (stage << 6)).astype(jnp.int32)
-        return VerdictState(word=word, eval_idx=vs.eval_idx + 1,
-                            term_eval=term_eval, term_it=term_it,
-                            best_cost=best, min_gn=ming, stage=stage,
-                            stall_anchor=anchor, stall_len=slen,
-                            stall_fired=sfired, hist=hist)
+        return _verdict_step_jit(graph, edges_g, Xa, weights, ready, mu,
+                                 rel_change, iteration, vs, tol,
+                                 n_total=n_total, num_meas=num_meas,
+                                 telemetry=telemetry, gnc=gnc, health=health)
 
     return verdict_step
 
@@ -1791,6 +1861,29 @@ def _crash_dump_scope(flight_rec):
         raise
 
 
+@partial(jax.jit, static_argnames=("n_total", "num_meas", "meta",
+                                   "certify_mode", "certify_seed"))
+def _epilogue_jit(graph, edges_g, Xa, weights, extras: dict, *, n_total,
+                  num_meas, meta: GraphMeta, certify_mode: str,
+                  certify_seed: int) -> dict:
+    Xg = gather_to_global(Xa, graph, n_total)
+    w_glob = global_weights(weights, graph, num_meas)
+    out = {"T": round_global(Xg, lifting_matrix(meta, Xg.dtype)),
+           "w_glob": w_glob, **extras}
+    if certify_mode in ("device", "host"):
+        # The lifted global iterate: the certificate operand, and the
+        # host f64 REFUSE fallback's input — riding the same fetch so
+        # a REFUSE never costs a second device round-trip.
+        out["Xg"] = Xg
+    if certify_mode == "device":
+        from . import certify as certify_mod
+
+        eg = edges_g._replace(weight=w_glob)
+        out["cert"] = certify_mod.device_certificate_payload(
+            Xg, eg, jax.random.PRNGKey(certify_seed))
+    return out
+
+
 def make_terminal_epilogue(graph: MultiAgentGraph, edges_g: EdgeSet,
                            n_total: int, num_meas: int, meta: GraphMeta, *,
                            certify_mode: str = "off",
@@ -1808,28 +1901,19 @@ def make_terminal_epilogue(graph: MultiAgentGraph, edges_g: EdgeSet,
     certificate mode is on — so the driver's entire epilogue (finalize +
     latched-index fetch + history fetch + certificate) collapses into a
     single blocking ``_host_fetch`` of the returned pytree.  The host
-    decision on the fetched payload is ``_epilogue_certificate``."""
-    device_cert = certify_mode == "device"
-    want_xg = certify_mode in ("device", "host")
-    if device_cert:
-        from . import certify as certify_mod
+    decision on the fetched payload is ``_epilogue_certificate``.
 
-    @jax.jit
+    ``epilogue`` binds ``graph``/``edges_g`` and calls the module-level
+    ``_epilogue_jit``, which takes the problem as arguments (sharded
+    arrays included): the executable is keyed on shapes, shardings and
+    the static settings (``meta``, ``certify_mode``, ``certify_seed``)
+    only, so every solve of one padded shape shares it."""
+
     def epilogue(Xa, weights, extras: dict) -> dict:
-        Xg = gather_to_global(Xa, graph, n_total)
-        w_glob = global_weights(weights, graph, num_meas)
-        out = {"T": round_global(Xg, lifting_matrix(meta, Xg.dtype)),
-               "w_glob": w_glob, **extras}
-        if want_xg:
-            # The lifted global iterate: the certificate operand, and the
-            # host f64 REFUSE fallback's input — riding the same fetch so
-            # a REFUSE never costs a second device round-trip.
-            out["Xg"] = Xg
-        if device_cert:
-            eg = edges_g._replace(weight=w_glob)
-            out["cert"] = certify_mod.device_certificate_payload(
-                Xg, eg, jax.random.PRNGKey(certify_seed))
-        return out
+        return _epilogue_jit(graph, edges_g, Xa, weights, extras,
+                             n_total=n_total, num_meas=num_meas, meta=meta,
+                             certify_mode=certify_mode,
+                             certify_seed=certify_seed)
 
     return epilogue
 
