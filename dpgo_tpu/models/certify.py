@@ -368,15 +368,18 @@ def f64_recheck(f64_solve, tol: float):
       lam_f64 + resid < -tol  => an eigenvalue below -tol exists
                                  (sound FAIL), and
       lam_f64 - resid >= -tol => the targeted bottom eigenvalue
-                                 clears -tol (PASS — trusting the
-                                 warm-started, gauge-deflated solve
-                                 targeted the minimal subspace,
-                                 the same trust assumption every
-                                 Krylov certificate makes).
+                                 clears -tol (PASS).
     Anything in between is refused.  This replaces the round-5 draft
     rule ``resid <= tol/2`` which refused a CONVERGED-to-0 eigenvalue
     whose residual (2e-4) merely missed an arbitrary threshold while
     the verdict itself was unambiguous.
+
+    Where ``lambda_min_f64_shift_invert`` decides by the inertia of
+    ``S + tol I`` (pose graphs whose sparse factorization is viable), the
+    PASS is exact: no eigenvalue lies below -tol.  On its fall-through
+    path, and on the small-problem LOBPCG path, the PASS rests on the
+    trust assumption every Krylov certificate makes: that the
+    warm-started, gauge-deflated solve targeted the minimal subspace.
 
     Returns ``(certified, decidable, lam_f64, vec64)``.
     """
@@ -696,61 +699,159 @@ def sparse_certificate(X64, edges: EdgeSet):
     return Q - L
 
 
+def _inertia_factor(S, tol_cert: float):
+    """Symmetric SuperLU factor of ``S + tol_cert I`` (minimum-degree order
+    on ``A + A^T``, diagonal pivots) and what its pivots decide.
+
+    With the row order equal to the column order the factor is
+    ``P A P^T = L D L^T`` (``D = diag(U)``), so by Sylvester's law of
+    inertia the pivots <= 0 count the eigenvalues of S at or below
+    ``-tol_cert`` exactly.  Returns ``(lu or None, outcome)``: ``"pass"``
+    (every pivot > 0, so ``lambda_min(S) > -tol_cert``), ``"fail"`` (a
+    pivot <= 0), ``"pivoted"`` (a pivot left the diagonal, or SuperLU
+    found the matrix singular).  Only a ``"pass"`` factor is positive
+    definite, hence stable to solve with."""
+    import numpy as np
+    from scipy import sparse
+    from scipy.sparse.linalg import splu
+
+    A = (S + tol_cert * sparse.eye(S.shape[0], format="csr")).tocsc()
+    try:
+        with obs.trace.span("cert.f64.inertia"):
+            lu = splu(A, permc_spec="MMD_AT_PLUS_A", diag_pivot_thresh=0.0,
+                      options={"SymmetricMode": True})
+    except RuntimeError:
+        return None, "pivoted"
+    if not np.array_equal(lu.perm_r, lu.perm_c):
+        return lu, "pivoted"
+    return lu, "pass" if bool((lu.U.diagonal() > 0).all()) else "fail"
+
+
+def _shift_invert_window(S, k: int, tol_cert: float, maxiter: int,
+                         lu=None):
+    """``eigsh(S, sigma=-tol_cert)``: the ``k`` eigenpairs nearest the
+    threshold, through ``lu`` (a factor of ``S + tol_cert I``) when given,
+    else through eigsh's own sparse LU.  Non-convergence salvages partial
+    pairs; ``(None, None)`` when there are none or the LU is singular."""
+    import numpy as np
+    from scipy.sparse.linalg import (ArpackNoConvergence, LinearOperator,
+                                     eigsh)
+
+    opinv = None if lu is None else LinearOperator(
+        S.shape, matvec=lu.solve, dtype=np.float64)
+    try:
+        return eigsh(S, k=k, sigma=-tol_cert, which="LM", maxiter=maxiter,
+                     tol=1e-10, OPinv=opinv)
+    except ArpackNoConvergence as e:
+        if e.eigenvalues is None or not len(e.eigenvalues):
+            return None, None
+        return e.eigenvalues, e.eigenvectors
+    except RuntimeError:
+        return None, None
+
+
+def _min_pair(S, vals, vecs):
+    """The smallest Ritz pair as a unit vector, with its explicit
+    residual ``||S v - lam v||``."""
+    import numpy as np
+
+    idx = int(np.argmin(vals))
+    lam, v = float(vals[idx]), vecs[:, idx]
+    v = v / max(np.linalg.norm(v), 1e-300)
+    return lam, v, float(np.linalg.norm(S @ v - lam * v))
+
+
+def _rq_veto(S, v):
+    """(Rayleigh quotient, normalized v), or None for a degenerate v —
+    RQ < -tol_cert is a sound FAIL certificate for any v."""
+    import numpy as np
+
+    v = np.asarray(v, np.float64).reshape(-1)
+    nv = np.linalg.norm(v)
+    if not np.isfinite(nv) or nv < 1e-300:
+        return None
+    v = v / nv
+    return float(v @ (S @ v)), v
+
+
+def _refusal(S):
+    """A pair the interval rule can never pass: a residual of at least
+    the spectral radius, and no vector, so the caller KEEPS its own (f32)
+    direction — a zero direction would silently no-op the staircase's
+    saddle escape."""
+    import numpy as np
+
+    return 0.0, None, float(np.abs(S).sum(axis=1).max())
+
+
+def _expander_guard(edges: EdgeSet, n: int, dh: int) -> bool:
+    """FILL GUARD: sparse LU is only viable on chain/planar-ish graphs.
+    A high fraction of long-range edges (random loop closures) makes the
+    graph an expander whose LU fill is near-dense — measured round 5:
+    >25 min and ~7 GB at 400k dims, twice, on the noisy 100k synthetic
+    (17% random LCs), vs seconds on the stitched-winding chain (1%
+    long-range bridges).  Such graphs never factor: when the guard trips
+    and the Krylov passes are inconclusive, the honest outcome is
+    REFUSAL, not an unbounded factorization."""
+    import numpy as np
+
+    i_np = np.asarray(edges.i)
+    j_np = np.asarray(edges.j)
+    msk = (np.asarray(edges.mask) > 0) if hasattr(edges, "mask") \
+        else np.ones_like(i_np, bool)
+    span = np.abs(i_np[msk] - j_np[msk])
+    long_frac = float(np.mean(span > max(64, n // 100))) if span.size \
+        else 0.0
+    return n * dh > 100_000 and long_frac > 0.05
+
+
 def lambda_min_f64_shift_invert(X64, edges: EdgeSet, tol_cert: float,
                                 k: int = 12, maxiter: int = 2000,
                                 warm=None):
-    """Minimum eigenvalue of S near the certification threshold via
-    shift-invert Lanczos on the explicit sparse operator.
-
-    ``eigsh(S, sigma=-tol_cert, which="LM")`` factorizes
-    ``S + tol_cert I`` (sparse LU) and converges to the eigenvalues
-    NEAREST the threshold — exactly the ones that decide certification —
-    with the spectral transformation providing the separation that plain
-    Krylov lacks inside near-zero clusters.  A negative outlier far
-    below the shift ranks above the (bounded-size) gauge cluster in the
-    transformed spectrum, so ``k`` directions cover it; ``k`` should
-    comfortably exceed the gauge dimension (r gauge rows + slack).
+    """Minimum eigenvalue of S near the certification threshold, on the
+    explicit sparse operator.
 
     Returns ``(lam_min, eigenvector [n, d+1] or None, resid)`` with
     ``resid`` the explicit eigenpair residual of the reported pair on S —
-    ``decide_certificate``'s two-sided interval rule consumes it.  The
-    vector is ``None`` when no eigenpair could be computed (the caller
-    must then keep its own direction estimate, e.g. the f32 one).
+    ``f64_recheck``'s two-sided interval rule consumes it.  The vector is
+    ``None`` when no eigenpair could be computed (the caller must then
+    keep its own direction estimate, e.g. the f32 one).
 
-    Soundness guard against the shift-invert window: ``eigsh(sigma)``
-    returns the eigenvalues NEAREST the shift, so a near-zero cluster
-    larger than ``k`` could crowd a genuinely negative lambda_min out of
-    the window and the window pair alone would falsely PASS.  Every
-    available direction is therefore screened by its RAYLEIGH QUOTIENT
-    on S — RQ(v) >= lambda_min for ANY v, so RQ(v) < -tol_cert is an
-    unconditional proof of failure (no residual required).  Screened
-    directions: the SA pass's Ritz vectors (converged or not) and the
-    caller's ``warm`` vector (the f32 eigensolve's direction — exactly
-    the direction a crowded window would miss).
+    Order of work:
+
+    1. The caller's ``warm`` direction (the f32 eigensolve's) is screened
+       by its RAYLEIGH QUOTIENT on S: RQ(v) >= lambda_min for ANY v, so
+       RQ(v) < -tol_cert is an unconditional FAIL, residual 0.
+    2. Inertia.  Unless ``_expander_guard`` trips, one symmetric
+       factorization of ``S + tol_cert I`` decides (``_inertia_factor``).
+       Every pivot > 0 proves ``lambda_min(S) > -tol_cert`` by Sylvester's
+       law, exact up to the factorization's rounding (with positive pivots
+       it is a Cholesky factor of a matrix within ~k eps max(S_ii) of
+       ``S + tol I``, k the fill of a row of L — orders below the
+       weight-scale tolerance).  The shift-invert window
+       (``eigsh(sigma=-tol_cert)`` through the SAME factor) then reports
+       the pair: with nothing below the threshold, the eigenvalues nearest
+       it are the bottom of the spectrum.  No SA or LOBPCG pass runs.
+    3. Otherwise (the guard tripped, a pivot <= 0, pivoting off the
+       diagonal, a singular factor, or a window that converged to nothing)
+       the Krylov passes of ``_krylov_passes`` run: SA Lanczos,
+       gauge-deflated LOBPCG, shift-invert through eigsh's own
+       partially pivoted LU (a symmetric factor of an indefinite matrix
+       has unbounded growth, so it is not reused).  Their PASS rests on
+       the trust assumption that some screened direction tracks the
+       minimal subspace — except after a pivot <= 0: the inertia has then
+       counted an eigenvalue at or below -tol_cert, and a pair that would
+       pass is reported as a refusal instead.
+
+    ``cert_inertia_total{outcome=pass|fail|pivoted|guarded}`` counts,
+    with a telemetry run, how the inertia decided each recheck that got
+    past the warm veto.
     """
     import numpy as np
-    from scipy.sparse.linalg import ArpackNoConvergence, eigsh
 
-    n, r, dh = np.asarray(X64).shape
+    n, _, dh = np.asarray(X64).shape
     with obs.trace.span("cert.f64.assemble"):
         S = sparse_certificate(X64, edges)
-
-    def pair(vals, vecs):
-        idx = int(np.argmin(vals))
-        lam, v = float(vals[idx]), vecs[:, idx]
-        v = v / max(np.linalg.norm(v), 1e-300)
-        resid = float(np.linalg.norm(S @ v - lam * v))
-        return lam, v, resid
-
-    def rq_veto(v):
-        """(rayleigh_quotient, normalized v) — RQ < -tol_cert is a sound
-        FAIL certificate for any v."""
-        v = np.asarray(v, np.float64).reshape(-1)
-        nv = np.linalg.norm(v)
-        if not np.isfinite(nv) or nv < 1e-300:
-            return None
-        v = v / nv
-        return float(v @ (S @ v)), v
 
     # A veto returns resid 0.0: the RQ bound is ONE-SIDED for free
     # (lambda_min <= RQ < -tol needs no eigenpair residual), and
@@ -758,9 +859,58 @@ def lambda_min_f64_shift_invert(X64, edges: EdgeSet, tol_cert: float,
     # exactly the sound conclusion.  The reported value is an upper
     # bound on lambda_min, which only ever understates the deficit.
     if warm is not None:
-        r_w = rq_veto(warm)
+        r_w = _rq_veto(S, warm)
         if r_w is not None and r_w[0] < -tol_cert:
             return r_w[0], r_w[1].reshape(n, dh), 0.0
+
+    guarded = _expander_guard(edges, n, dh)
+    vals = vecs = None
+    if guarded:
+        outcome = "guarded"
+    else:
+        with obs.trace.span("cert.f64.shift_invert"):
+            lu, outcome = _inertia_factor(S, tol_cert)
+            if outcome == "pass":
+                vals, vecs = _shift_invert_window(S, k, tol_cert, maxiter,
+                                                  lu)
+    run = obs.get_run()
+    if run is not None:
+        run.counter("cert_inertia_total",
+                    "host f64 rechecks by what the inertia of S + tol I "
+                    "decided").inc(outcome=outcome)
+    if outcome == "pass" and vals is not None:
+        lam, v, resid = _min_pair(S, vals, vecs)
+        return lam, v.reshape(n, dh), resid
+
+    lam, v, resid = _krylov_passes(S, X64, tol_cert, k, maxiter, warm,
+                                   guarded)
+    if outcome == "fail" and lam - resid >= -tol_cert:
+        # The inertia counted an eigenvalue at or below -tol that no
+        # screened direction found: the window missed the bottom.
+        # Refuse (lam - resid < -tol, lam + resid >= -tol), with no
+        # vector: the window's has RQ >= -tol, no descent direction, so
+        # the caller keeps its own.
+        return lam, None, lam + 2.0 * tol_cert
+    return lam, None if v is None else v.reshape(n, dh), resid
+
+
+def _krylov_passes(S, X64, tol_cert: float, k: int, maxiter: int, warm,
+                   guarded: bool):
+    """The recheck's passes where the inertia did not decide: SA Lanczos,
+    gauge-deflated LOBPCG, then shift-invert at the threshold (never
+    where ``guarded``).  Returns ``(lam, flat vector or None, resid)``.
+
+    ``eigsh(sigma)`` returns the eigenvalues NEAREST the shift, so a
+    near-zero cluster larger than ``k`` could crowd a genuinely negative
+    lambda_min out of the window, and the window pair alone would falsely
+    PASS (``k`` should comfortably exceed the gauge dimension, r rows +
+    slack).  Every available direction is therefore screened by its
+    Rayleigh quotient — the SA pass's Ritz vectors (converged or not) and
+    the LOBPCG direction — so any RQ < -tol_cert is a sound FAIL."""
+    import numpy as np
+    from scipy.sparse.linalg import ArpackNoConvergence, eigsh
+
+    n, r, dh = np.asarray(X64).shape
 
     # Pass 1 — plain smallest-algebraic Lanczos: converges fast exactly
     # when lambda_min is a SEPARATED negative outlier (the case the
@@ -772,20 +922,20 @@ def lambda_min_f64_shift_invert(X64, edges: EdgeSet, tol_cert: float,
     try:
         with obs.trace.span("cert.f64.sa"):
             vals, vecs = eigsh(S, k=4, which="SA", maxiter=60, tol=1e-7)
-        lam_sa, v_sa, r_sa = pair(vals, vecs)
+        lam_sa, v_sa, r_sa = _min_pair(S, vals, vecs)
     except ArpackNoConvergence as e:
         lam_sa = v_sa = r_sa = None
         if getattr(e, "eigenvalues", None) is not None \
                 and len(e.eigenvalues):
-            lam_sa, v_sa, r_sa = pair(e.eigenvalues, e.eigenvectors)
+            lam_sa, v_sa, r_sa = _min_pair(S, e.eigenvalues, e.eigenvectors)
     if lam_sa is not None and lam_sa < -tol_cert:
         # Recompute the RQ explicitly: a salvaged unconverged ARPACK
         # Ritz value can deviate from the true RQ of its vector (lost
         # orthogonality ~ eps * sigma); the SOUND bound is the explicit
         # v @ S v of the actual unit vector, not the reported value.
-        r_sa_rq = rq_veto(v_sa)
+        r_sa_rq = _rq_veto(S, v_sa)
         if r_sa_rq is not None and r_sa_rq[0] < -tol_cert:
-            return r_sa_rq[0], r_sa_rq[1].reshape(n, dh), 0.0
+            return r_sa_rq[0], r_sa_rq[1], 0.0
 
     # Pass 2 — gauge-deflated LOBPCG on the SPARSE operator.  Complement
     # of the shift-invert pass below: on well-connected graphs (random
@@ -823,14 +973,14 @@ def lambda_min_f64_shift_invert(X64, edges: EdgeSet, tol_cert: float,
             vals_l, vecs_l = _lobpcg(S, V0, Y=Yc, largest=False, maxiter=300,
                                      tol=min(1e-8, 0.1 * tol_cert),
                                      verbosityLevel=0)
-        lam_l, v_l, r_l = pair(vals_l, vecs_l)
+        lam_l, v_l, r_l = _min_pair(S, vals_l, vecs_l)
         rq_l = float(v_l @ (S @ v_l))  # explicit RQ of the unit vector
         lam_l_full = min(lam_l, 0.0)  # gauge zeros complete the spectrum
         if rq_l < -tol_cert:
             # Rayleigh quotient of a genuine unit vector: sound FAIL.
-            return rq_l, v_l.reshape(n, dh), 0.0
+            return rq_l, v_l, 0.0
         if defl_ok and lam_l_full - r_l >= -tol_cert:
-            return lam_l_full, v_l.reshape(n, dh), r_l
+            return lam_l_full, v_l, r_l
     except (np.linalg.LinAlgError, ValueError) as e:
         # The EXPECTED numerical failures of deflated LOBPCG (singular
         # Gram/basis breakdown -> LinAlgError; degenerate block shapes ->
@@ -850,59 +1000,31 @@ def lambda_min_f64_shift_invert(X64, edges: EdgeSet, tol_cert: float,
     # shift lands on an eigenvalue) must REFUSE, not crash a multi-hour
     # staircase: salvage partial eigenpairs when present, else return a
     # pair whose residual can never pass the interval rule.
-    # FILL GUARD: sparse LU is only viable on chain/planar-ish graphs.
-    # A high fraction of long-range edges (random loop closures) makes
-    # the graph an expander whose LU fill is near-dense — measured
-    # round 5: >25 min and ~7 GB at 400k dims, twice, on the noisy 100k
-    # synthetic (17% random LCs), vs seconds on the stitched-winding
-    # chain (1% long-range bridges).  When the guard trips and the
-    # Krylov tiers above were inconclusive, the honest outcome is
-    # REFUSAL, not an unbounded factorization.
-    i_np = np.asarray(edges.i)
-    j_np = np.asarray(edges.j)
-    msk = (np.asarray(edges.mask) > 0) if hasattr(edges, "mask") \
-        else np.ones_like(i_np, bool)
-    span = np.abs(i_np[msk] - j_np[msk])
-    long_frac = float(np.mean(span > max(64, n // 100))) if span.size \
-        else 0.0
-    if n * dh > 100_000 and long_frac > 0.05:
+    if guarded:
         if lam_sa is not None:
-            return lam_sa, v_sa.reshape(n, dh), r_sa
-        big = float(np.abs(S).sum(axis=1).max())
-        return 0.0, None, big
-    try:
-        with obs.trace.span("cert.f64.shift_invert"):
-            vals, vecs = eigsh(S, k=k, sigma=-tol_cert, which="LM",
-                               maxiter=maxiter, tol=1e-10)
-    except ArpackNoConvergence as e:
-        vals, vecs = e.eigenvalues, e.eigenvectors
-        if vals is None or not len(vals):
-            vals, vecs = None, None
-    except RuntimeError:
-        vals = vecs = None
+            return lam_sa, v_sa, r_sa
+        return _refusal(S)
+    with obs.trace.span("cert.f64.shift_invert"):
+        vals, vecs = _shift_invert_window(S, k, tol_cert, maxiter)
     if vals is None:
         if lam_sa is not None:
-            return lam_sa, v_sa.reshape(n, dh), r_sa
-        # Total failure: refuse (a huge residual can never pass the
-        # interval rule).  Vector is None so the caller KEEPS its own
-        # (f32) direction — a zero direction would silently no-op the
-        # staircase's saddle escape.
-        big = float(np.abs(S).sum(axis=1).max())  # >= spectral radius
-        return 0.0, None, big
-    lam, v, resid = pair(vals, vecs)
+            return lam_sa, v_sa, r_sa
+        return _refusal(S)
+    lam, v, resid = _min_pair(S, vals, vecs)
     if lam_sa is not None and lam_sa + r_sa < lam - resid:
         # The SA interval proves a true eigenvalue strictly below
         # everything the shift-invert window saw — the window missed
         # the bottom, so its PASS would be unsound; report the more
         # pessimistic SA pair (refusal) instead.
-        return lam_sa, v_sa.reshape(n, dh), r_sa
-    # The window's Ritz values are RQs too: pair() took the argmin, so a
-    # window member below -tol decides FAIL through the interval rule
+        return lam_sa, v_sa, r_sa
+    # The window's Ritz values are RQs too: _min_pair took the argmin, so
+    # a window member below -tol decides FAIL through the interval rule
     # with its (tiny) residual.  At this point every screened direction
     # (warm, SA Ritz, window) has RQ >= -tol; the PASS still rests on
     # the documented trust assumption that SOME screened direction
-    # tracks the minimal subspace.
-    return lam, v.reshape(n, dh), resid
+    # tracks the minimal subspace — the assumption the inertia pass
+    # removes wherever the guard lets it factor.
+    return lam, v, resid
 
 
 def lambda_min_f64(X64, edges: EdgeSet, warm=None, num_probe: int = 4,

@@ -45,7 +45,9 @@ robustness change reports through:
   ``rbcd.enqueue``, ``rbcd.fetch{what=word|history|epilogue|eval}``,
   ``cert.decide``), and inside the certificate ``cert.f64``,
   ``cert.f64.assemble``, ``cert.f64.sa``, ``cert.f64.lobpcg``,
-  ``cert.f64.shift_invert``.
+  ``cert.f64.shift_invert`` (> ``cert.f64.inertia``, the symmetric
+  factorization whose pivots decide; counter
+  ``cert_inertia_total{outcome}``).
 * Compile counters (``compile.py``) — JAX's trace / lower / build
   durations and persistent-cache hits, forwarded from ``jax.monitoring``
   as ``jax_compile_seconds_total{stage, program, span}`` and

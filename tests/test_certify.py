@@ -254,3 +254,152 @@ def test_sparse_certificate_matches_dense(rng):
         np.asarray(Xw, np.float64), edgesw, tol_cert=1e-4)
     assert resid < 1e-8
     assert abs(lam - lam_dense) < 1e-8 * max(1.0, abs(lam_dense))
+
+
+# ---------------------------------------------------------------------------
+# The host f64 recheck's inertia pass (``lambda_min_f64_shift_invert``)
+# ---------------------------------------------------------------------------
+
+def _optimum(rng):
+    meas, _ = make_measurements(rng, n=12, d=3, num_lc=6,
+                                rot_noise=0.05, trans_noise=0.05)
+    res = local_pgo.solve_local(meas, rank=5, grad_norm_tol=1e-9,
+                                max_iters=500)
+    edges = edge_set_from_measurements(meas, dtype=jnp.float64)
+    return np.asarray(res.X, np.float64), edges
+
+
+def _saddle():
+    from dpgo_tpu.utils.synthetic import make_stitched_winding
+
+    meas, Xw = make_stitched_winding(3, 12)
+    edges = edge_set_from_measurements(meas, dtype=jnp.float64)
+    return np.asarray(Xw, np.float64), edges
+
+
+def _spy_inertia(monkeypatch):
+    """Record what every ``_inertia_factor`` call returned."""
+    seen = []
+    real = certify._inertia_factor
+
+    def spy(S, tol_cert):
+        out = real(S, tol_cert)
+        seen.append(out)
+        return out
+    monkeypatch.setattr(certify, "_inertia_factor", spy)
+    return seen
+
+
+@pytest.mark.parametrize("case", ["optimum", "saddle", "shifted"])
+def test_inertia_counts_eigenvalues_below_tol(case, rng, monkeypatch):
+    """The pivots <= 0 of the symmetric factor of ``S + tol I`` count
+    exactly the eigenvalues of S below -tol (Sylvester's law): none at a
+    certified optimum, some at a wound saddle, and one just below the
+    threshold in an S shifted to put it there."""
+    tol = 1e-4
+    X, edges = _saddle() if case == "saddle" else _optimum(rng)
+    S = certify.sparse_certificate(X, edges)
+    if case == "shifted":
+        # Move the 8th eigenvalue (a gapped one) to tol * 1e-3 below -tol.
+        lam = np.linalg.eigvalsh(S.toarray())
+        t = 7 + int(np.argmax(np.diff(lam[7:20]) > 1e-2))
+        shift = lam[t] + tol * (1.0 + 1e-3)
+        S_shifted = S - shift * np.eye(S.shape[0])
+        from scipy import sparse
+        S = sparse.csr_matrix(S_shifted)
+        monkeypatch.setattr(certify, "sparse_certificate",
+                            lambda X64, e: S)
+    seen = _spy_inertia(monkeypatch)
+    certify.lambda_min_f64_shift_invert(X, edges, tol)
+    (lu, outcome), = seen
+    below = int(np.sum(np.linalg.eigvalsh(S.toarray()) < -tol))
+    assert np.array_equal(lu.perm_r, lu.perm_c)
+    assert int(np.sum(lu.U.diagonal() <= 0)) == below
+    assert outcome == ("pass" if below == 0 else "fail")
+    assert (below == 0) == (case == "optimum")
+    if case == "shifted":
+        assert below == t + 1
+
+
+def test_inertia_pass_skips_krylov_passes(rng, tmp_path):
+    """On a certified optimum the inertia decides: no SA or LOBPCG span
+    is recorded, and the verdict and lambda_min agree with dense
+    ``eigvalsh``."""
+    from dpgo_tpu import obs
+
+    tol = 1e-4
+    X, edges = _optimum(rng)
+    lam_dense = float(np.linalg.eigvalsh(
+        certify.sparse_certificate(X, edges).toarray())[0])
+    obs.end_run()
+    with obs.run_scope(str(tmp_path / "run")) as run:
+        certified, decidable, lam, _ = certify.f64_recheck(
+            lambda t: certify.lambda_min_f64_shift_invert(X, edges, tol), tol)
+        snap = run.registry.snapshot()
+    spans = {s["labels"]["span"] for s in snap["span_seconds"]["series"]}
+    assert "cert.f64.inertia" in spans
+    assert not spans & {"cert.f64.sa", "cert.f64.lobpcg"}
+    assert certified and decidable and lam_dense >= -tol
+    assert abs(lam - lam_dense) < 1e-8 * max(1.0, abs(lam_dense))
+
+
+def test_inertia_fail_falls_through_to_a_sound_direction():
+    """A wound saddle has a pivot <= 0: the Krylov passes run and FAIL
+    with a direction whose Rayleigh quotient is below -tol."""
+    tol = 1e-4
+    X, edges = _saddle()
+    S = certify.sparse_certificate(X, edges)
+    certified, decidable, lam, vec = certify.f64_recheck(
+        lambda t: certify.lambda_min_f64_shift_invert(X, edges, tol), tol)
+    assert not certified and decidable
+    v = vec.reshape(-1) / np.linalg.norm(vec)
+    assert float(v @ (S @ v)) < -tol
+
+
+def test_expander_guard_never_factors(monkeypatch):
+    """Past the expander guard (over 100,000 dims with > 5% long-range
+    edges) the symmetric factorization is never attempted and the Krylov
+    passes decide."""
+    from dpgo_tpu.utils.synthetic import make_measurements_vectorized
+
+    def boom(*_):
+        raise AssertionError("factored a graph the guard rules out")
+    monkeypatch.setattr(certify, "_inertia_factor", boom)
+    meas, (Rs, ts) = make_measurements_vectorized(
+        np.random.default_rng(3), n=25_001, d=3, num_lc=5_000)
+    X = np.concatenate([Rs, ts[:, :, None]], axis=2)  # rank 3 = d
+    edges = edge_set_from_measurements(meas, dtype=jnp.float64)
+    assert certify._expander_guard(edges, 25_001, 4)
+    # The Krylov passes at 100,000 dims are not what is tested here.
+    monkeypatch.setattr(certify, "_krylov_passes",
+                        lambda *a: (0.0, None, 1.0))
+    assert certify.lambda_min_f64_shift_invert(X, edges, 1e-3) \
+        == (0.0, None, 1.0)
+
+
+@pytest.mark.parametrize("outcome", ["fail", "pivoted"])
+def test_undecided_inertia_refactors_stably(outcome, rng, monkeypatch):
+    """Where the pivots do not prove a PASS, the Krylov passes' window
+    does not solve with the symmetric factor (unbounded growth on an
+    indefinite matrix) but through eigsh's own LU.  After a pivot <= 0 a
+    window pair that would pass is refused, with no vector (its RQ is
+    >= -tol, no descent direction); after off-diagonal pivoting it
+    passes."""
+    tol = 1e-4
+    X, edges = _optimum(rng)
+    monkeypatch.setattr(certify, "_inertia_factor",
+                        lambda S, t: (None, outcome))
+    windows = []
+    real = certify._shift_invert_window
+
+    def spy(S, k, tol_cert, maxiter, lu=None):
+        windows.append(lu)
+        return real(S, k, tol_cert, maxiter, lu)
+    monkeypatch.setattr(certify, "_shift_invert_window", spy)
+    lam, vec, resid = certify.lambda_min_f64_shift_invert(X, edges, tol)
+    assert windows == [None]
+    if outcome == "fail":
+        assert vec is None
+        assert lam - resid < -tol <= lam + resid  # refused
+    else:
+        assert vec is not None and lam - resid >= -tol  # passes

@@ -220,3 +220,52 @@ def _lifted_chain(meas: Measurements) -> np.ndarray:
     pose = np.concatenate([np.stack(Rs), np.stack(ts)[:, :, None]], axis=2)
     Y = np.linalg.qr(np.random.default_rng(0).standard_normal((5, d)))[0]
     return np.einsum("rd,nde->nre", Y, pose)
+
+
+@pytest.mark.parametrize("outcome", ["pass", "fail", "pivoted",
+                                     "guarded"])
+def test_f64_recheck_counts_inertia_outcome(outcome, tmp_path, monkeypatch):
+    """Each host f64 recheck adds one to ``cert_inertia_total`` under the
+    outcome of its inertia pass, and the factorization's
+    ``cert.f64.inertia`` span nests inside ``cert.f64.shift_invert``."""
+    import scipy.sparse.linalg
+
+    from dpgo_tpu.utils.synthetic import make_stitched_winding
+
+    obs.end_run()
+    meas, Xw = make_stitched_winding(2, 12)
+    edges = edge_set_from_measurements(meas, dtype=jnp.float64)
+    X = np.asarray(Xw, np.float64)
+    tol = 1e-4
+    if outcome == "pass":
+        # A wound saddle's S shifted up past its bottom eigenvalue.
+        S = certify.sparse_certificate(X, edges)
+        lam0 = float(np.linalg.eigvalsh(S.toarray())[0])
+        S_up = (S - 2.0 * lam0 * scipy.sparse.eye(S.shape[0])).tocsr()
+        monkeypatch.setattr(certify, "sparse_certificate",
+                            lambda X64, e: S_up)
+    elif outcome == "pivoted":
+        def singular(*_, **__):
+            raise RuntimeError("Factor is exactly singular")
+        monkeypatch.setattr(scipy.sparse.linalg, "splu", singular)
+    elif outcome == "guarded":
+        monkeypatch.setattr(certify, "_expander_guard", lambda *_: True)
+    with obs.run_scope(str(tmp_path / "run")) as run:
+        for _ in range(2):
+            certify.f64_recheck(
+                lambda t: certify.lambda_min_f64_shift_invert(X, edges, tol),
+                tol)
+        snap = run.registry.snapshot()
+    (c,) = snap["cert_inertia_total"]["series"]
+    assert c["labels"] == {"outcome": outcome} and c["value"] == 2.0
+    spans = [e for e in read_events(str(tmp_path / "run" / "events.jsonl"))
+             if e["event"] == "span"]
+    inertia = [e for e in spans if e["name"] == "cert.f64.inertia"]
+    assert len(inertia) == (0 if outcome == "guarded" else 2)
+    parents = {e["span"]: e for e in spans
+               if e["name"] == "cert.f64.shift_invert"}
+    for e in inertia:
+        assert e["parent"] in parents
+        assert e["dur_s"] <= parents[e["parent"]]["dur_s"]
+    sa = _series(snap, "span_seconds", span="cert.f64.sa")
+    assert bool(sa) == (outcome != "pass")
