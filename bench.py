@@ -178,17 +178,15 @@ def time_verdict_loop(device, dtype, rounds, k):
     state0, graph, meta, params, part = build(dtype, never_terminate=True)
     state0 = jax.device_put(state0, device)
     graph = jax.device_put(graph, device)
-    step = lambda s, uw, rs: rbcd.rbcd_step(s, graph, meta, params,
-                                            update_weights=uw, restart=rs)
     seg = lambda s, kk, uw, rs: rbcd.rbcd_segment(s, graph, kk, meta,
                                                   params,
                                                   first_update_weights=uw,
                                                   first_restart=rs)
 
     def drive(n_rounds):
-        return rbcd.run_rbcd(state0, graph, meta, step, part, n_rounds,
+        return rbcd.run_rbcd(state0, graph, meta, seg, part, n_rounds,
                              grad_norm_tol=0.0, eval_every=k, dtype=dtype,
-                             params=params, segment=seg, verdict_every=k)
+                             params=params, verdict_every=k)
 
     # Warm-up compiles the segment, verdict, and finalize programs with
     # the exact call pattern of the timed trials (a structurally

@@ -153,17 +153,12 @@ def sharded_driver(mesh, part, graph, meta, state, params, dtype, k):
     from dpgo_tpu.models import rbcd
     from dpgo_tpu.parallel import (make_sharded_metrics_body,
                                    make_sharded_multi_step,
-                                   make_sharded_segment, make_sharded_step,
-                                   shard_problem)
+                                   make_sharded_segment, shard_problem)
     from dpgo_tpu.types import edge_set_from_measurements
 
     state, graph_s = shard_problem(mesh, state, graph)
-    sh_step = make_sharded_step(mesh, meta, params)
     sh_multi = make_sharded_multi_step(mesh, meta, params)
     sh_seg = make_sharded_segment(mesh, meta, params)
-    step = lambda s, uw, rs: sh_step(s, graph_s, update_weights=uw,
-                                     restart=rs)
-    multi = lambda s, kk: sh_multi(s, graph_s, kk)
     seg = lambda s, kk, uw, rs: sh_seg(s, graph_s, kk, update_weights=uw,
                                        restart=rs)
     edges_g = edge_set_from_measurements(part.meas_global, dtype=dtype)
@@ -172,10 +167,10 @@ def sharded_driver(mesh, part, graph, meta, state, params, dtype, k):
         len(part.meas_global), tel)
 
     def drive(rounds):
-        return rbcd.run_rbcd(state, graph_s, meta, step, part, rounds,
+        return rbcd.run_rbcd(state, graph_s, meta, seg, part, rounds,
                              grad_norm_tol=0.0, eval_every=k, dtype=dtype,
-                             params=params, multi_step=multi, segment=seg,
-                             verdict_every=k, metrics_body_factory=factory)
+                             params=params, verdict_every=k,
+                             metrics_body_factory=factory)
 
     return drive, state, graph_s, sh_multi
 

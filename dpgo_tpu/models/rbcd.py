@@ -1483,13 +1483,12 @@ def schedule_bounds(n_done: int, nwu: int, *, max_iters: int,
 
 def _central_metrics_body(graph: MultiAgentGraph, edges_g: EdgeSet,
                           n_total: int, num_meas: int, telemetry: bool):
-    """The (unjitted) stacked-eval computation shared by
-    ``_make_central_metrics`` and the fused verdict program
-    (``make_verdict_program``): both trace the *same* Python body, so the
-    per-eval rows the verdict program stores in its device-side history
-    are bit-identical to what the standalone metrics program fetches —
-    the flight-recorder replay contract extends across the verdict seam
-    (pinned by ``tests/test_recorder.py``)."""
+    """The (unjitted) stacked-eval computation shared by the fused verdict
+    program (``make_verdict_program``, and its batched twin in
+    ``serve.runner``) and the replay program ``_make_central_metrics``:
+    all trace the *same* Python body, so the per-eval rows the verdict
+    program stores in its device-side history are bit-identical to what
+    the replay recomputes (pinned by ``tests/test_recorder.py``)."""
 
     def central_metrics(Xa, weights, ready, mu, rel_change):
         Xg = gather_to_global(Xa, graph, n_total)
@@ -1520,12 +1519,11 @@ def _central_metrics_jit(graph, edges_g, Xa, weights, ready, mu, rel_change,
 
 def _make_central_metrics(graph: MultiAgentGraph, edges_g: EdgeSet,
                           n_total: int, num_meas: int, telemetry: bool):
-    """The jitted per-eval readback program of ``run_rbcd`` — one stacked
-    output = ONE device->host transfer per eval (each separate scalar
-    fetch would be its own device->host round-trip).  Factored out so the
-    flight recorder's replay evaluates the recorded trajectory through the
-    byte-identical XLA program (bit-for-bit reproduction requires the same
-    compiled reduction order, not merely the same math).
+    """The jitted stacked-metrics program of the flight recorder's replay
+    (``obs.recorder``): it evaluates a replayed trajectory through the
+    same ``_central_metrics_body`` the verdict program stores its history
+    rows from (bit-for-bit reproduction requires the same compiled
+    reduction order, not merely the same math).
 
     The returned callable binds the problem and calls the module-level
     ``_central_metrics_jit``, which takes ``graph``/``edges_g`` as
@@ -1544,8 +1542,8 @@ def _make_central_metrics(graph: MultiAgentGraph, edges_g: EdgeSet,
 # Device-resident verdict loop
 # ---------------------------------------------------------------------------
 #
-# The verdict word is one packed int32 the host reads back every K rounds in
-# place of the full per-eval scalar stack:
+# The verdict word is one packed int32 the host reads back every K rounds
+# (every eval by default) in place of the full per-eval scalar stack:
 #
 #   bits 0-2   status        0 RUNNING | 1 GRAD_NORM | 2 CONSENSUS
 #   bits 3-5   anomaly class 0 none | 1 cost_spike | 2 stall
@@ -1554,12 +1552,12 @@ def _make_central_metrics(graph: MultiAgentGraph, edges_g: EdgeSet,
 #   bits 6+    GNC stage index (robust.gnc_stage_index, 0 when not robust)
 #
 # Termination latches ON DEVICE at the first eval whose gradient norm
-# clears the tolerance (or whose agents reach consensus); the host only
+# clears the tolerance (or whose agents reach consensus) — the one
+# termination test of every driver (``_verdict_update``); the host only
 # learns about it at the next K-round fetch, so the returned iterate may
 # carry up to K - eval_every extra polish rounds past the terminal eval —
 # histories, telemetry, and ``iterations`` are truncated at the latched
-# terminal eval, so the *reported* trajectory is identical to the
-# per-eval path's.
+# terminal eval, so the *reported* trajectory is the same for every K.
 
 VERDICT_RUNNING = 0
 VERDICT_GRAD_NORM = 1
@@ -1660,12 +1658,14 @@ def _verdict_update(vec, mu, rel_change, iteration, vs: VerdictState,
                     grad_norm_tol, *, gnc, health) -> VerdictState:
     """THE verdict update of one eval: everything downstream of the
     stacked metrics row ``vec`` (convergence test, health predicates,
-    latch, history append).  One pure function traced by both the
-    single-device program (``_verdict_step_jit``) and the sharded plane's
-    ``metrics_body=`` closure, so the word semantics cannot drift between
-    the two.  ``gnc`` is None or the static ``(mu0, step, kmax)``;
-    ``health`` the static thresholds of ``_verdict_statics``;
-    ``grad_norm_tol`` may be traced."""
+    latch, history append) — the only place an RBCD solve's termination
+    is decided.  One pure function traced by the single-device program
+    (``_verdict_step_jit``), the sharded plane's ``metrics_body=``
+    closure, and the serving plane's batched program (a ``vmap`` of
+    ``_verdict_step_jit`` in ``serve.runner``), so the word semantics
+    cannot drift between them.  ``gnc`` is None or the static
+    ``(mu0, step, kmax)``; ``health`` the static thresholds of
+    ``_verdict_statics``; ``grad_norm_tol`` may be traced."""
     spike_rtol, spike_atol, expl_factor, gn_floor, stall_window, \
         stall_rtol = health
     f, gn, consensus = vec[0], vec[1], vec[2]
@@ -1788,7 +1788,7 @@ def make_verdict_program(graph: MultiAgentGraph, edges_g: EdgeSet,
     word's anomaly class is the in-band signal; with telemetry on the
     host-side monitor re-judges the fetched rows and remains the single
     authority for anomaly *events* and abort policy, so the emitted event
-    stream is identical to the per-eval path's.
+    stream does not depend on the fetch cadence K.
 
     ``max_evals`` bounds the history; the driver never records more rows
     than eval boundaries in ``max_iters``.  ``health_cfg`` duck-types
@@ -1950,15 +1950,13 @@ def run_rbcd(
     state: RBCDState,
     graph: MultiAgentGraph,
     meta: GraphMeta,
-    step,
+    segment,
     part: Partition,
     max_iters: int,
     grad_norm_tol: float = 0.1,
     eval_every: int = 1,
     dtype=jnp.float64,
     params: AgentParams | None = None,
-    multi_step=None,
-    segment=None,
     verdict_every: int | None = None,
     metrics_body_factory=None,
     start_iteration: int = 0,
@@ -1967,113 +1965,77 @@ def run_rbcd(
 ) -> RBCDResult:
     """The driver loop shared by the single-device and mesh-sharded solvers —
     the analog of the ``multi-robot-example`` loop
-    (``MultiRobotExample.cpp:175-264``): per round ``step`` exchanges public
-    poses and updates agents per the schedule; the centralized cost/gradnorm
-    trace (the demo's oracle) gates termination at ``grad_norm_tol`` (0.1 in
-    the reference driver), with agent consensus (all ``ready``) as the
-    deployed alternative (``shouldTerminate``, ``PGOAgent.cpp:1007-1031``).
+    (``MultiRobotExample.cpp:175-264``).
 
-    ``step(state, update_weights, restart)`` receives the two host-side
-    static schedule flags each round.  ``params`` drives the GNC /
-    acceleration schedules (omit for plain L2 RBCD).
+    ``segment(state, k, update_weights, restart)`` runs ``k`` rounds in
+    one device call, the first carrying the two host-side static schedule
+    flags (``rbcd_segment`` / the shard_map equivalent): the driver
+    dispatches once per schedule segment — the stretch between
+    weight-update/restart/eval rounds — so no round costs its own host
+    round-trip.  The GNC weight freeze runs on-device (see
+    ``_rbcd_round``), so nothing reads weights back between evals.
+    ``params`` drives the GNC / acceleration schedules (omit for plain L2
+    RBCD).
 
-    ``multi_step(state, k)``, when given, runs ``k`` consecutive plain
-    rounds in one device call (``rbcd_steps`` / the shard_map equivalent);
-    the driver then dispatches once per schedule segment — the stretch
-    between weight-update/restart/eval rounds — instead of once per round,
-    which removes the host round-trip that dominates wall-clock on fast
-    devices.  Identical math either way (the fused body is ``_rbcd_round``).
-
-    ``segment(state, k, update_weights, restart)``, when given, supersedes
-    both: each dispatch covers a flagged first round AND the plain stretch
-    to the next flag/eval boundary (``rbcd_segment`` / the shard_map
-    equivalent), so flagged rounds stop costing their own round-trips.
-    The GNC weight freeze runs on-device either way (see ``_rbcd_round``),
-    so no path reads weights back between evals.
-
-    ``verdict_every`` (K, a positive multiple of ``eval_every``) switches
-    the driver to the DEVICE-RESIDENT verdict loop: the centralized
-    metrics, the convergence test, and the health predicates run in the
-    fused verdict program at every eval boundary (``make_verdict_program``
-    — requires ``segment``), termination latches on device, and the host
-    reads back ONE packed verdict word per K rounds instead of the full
-    scalar stack per eval.  With telemetry on, the device-side eval
-    history is fetched lazily at each verdict boundary and replayed
-    through the same gauges/events/health-monitor/flight-recorder calls,
-    so the emitted event stream is identical to the per-eval path's (with
-    at most K rounds of latency); with telemetry off, only the word and
-    ONE fused terminal epilogue fetch (rounded trajectory, collapsed
-    weights, history, latched indices, and — with
-    ``params.certify_mode="device"`` — the dual-certificate payload)
-    ever cross the link.  Because the host learns
-    of termination at the next boundary, the returned iterate may carry
-    up to ``K - eval_every`` extra polish rounds; reported histories and
-    ``iterations`` are truncated at the latched terminal eval.
+    Termination is decided on the DEVICE.  At every eval boundary the
+    fused verdict program (``make_verdict_program``) evaluates the
+    centralized cost/gradnorm trace (the demo's oracle), tests the
+    gradient norm against ``grad_norm_tol`` (0.1 in the reference driver)
+    and agent consensus (all ``ready``, the deployed alternative —
+    ``shouldTerminate``, ``PGOAgent.cpp:1007-1031``), folds the health
+    predicates into the packed verdict word, and latches the first
+    terminal eval.  The host reads back ONE word every ``verdict_every``
+    rounds (K, a positive multiple of ``eval_every``; None reads it at
+    every eval).  With telemetry on, the device-side eval history is
+    fetched lazily at each boundary and replayed through the
+    gauges/events/health-monitor/flight-recorder calls; with telemetry
+    off, only the words and ONE fused terminal epilogue fetch (rounded
+    trajectory, collapsed weights, history, latched indices, and — with
+    ``params.certify_mode="device"`` — the dual-certificate payload) ever
+    cross the link.  Because the host learns of termination at the next
+    boundary, the returned iterate may carry up to ``K - eval_every``
+    extra polish rounds; reported histories and ``iterations`` are
+    truncated at the latched terminal eval.
 
     ``metrics_body_factory`` (mesh path) supplies a replacement for the
     stacked-metrics body: called once with the resolved telemetry flag, the
-    returned function is jitted for the per-eval readback AND handed to
-    ``make_verdict_program`` as its ``metrics_body`` — how the sharded
-    solver runs the centralized evals as a shard_map program with psum
-    reductions while sharing every downstream line of this driver.
+    returned function is handed to ``make_verdict_program`` as its
+    ``metrics_body`` — how the sharded solver runs the centralized evals
+    as a shard_map program with psum reductions while sharing every
+    downstream line of this driver.
 
-    ``start_iteration`` / ``start_num_weight_updates`` resume the verdict
-    loop mid-schedule from a checkpointed state (``parallel.resilience``):
-    the schedule arithmetic is a pure function of the ABSOLUTE round
-    index, so a resumed solve replays the exact flag sequence of the
-    uninterrupted one.  ``boundary_cb(it, nwu, state, word, terminal)``
-    fires at every verdict boundary with the pre-speculation state — the
-    checkpoint/rewind hook; it may raise to abort the attempt.  All three
-    require the verdict loop."""
-    if verdict_every is None and (start_iteration or start_num_weight_updates
-                                  or boundary_cb is not None):
+    ``start_iteration`` / ``start_num_weight_updates`` resume the loop
+    mid-schedule from a checkpointed state (``parallel.resilience``): the
+    schedule arithmetic is a pure function of the ABSOLUTE round index, so
+    a resumed solve replays the exact flag sequence of the uninterrupted
+    one.  A resumed attempt gets a fresh verdict state — anomaly latches
+    clear, and its history rows cover only the resumed suffix.
+    ``boundary_cb(it, nwu, state, word, terminal)`` fires at every
+    verdict boundary with the pre-speculation state — the
+    checkpoint/rewind hook; it may raise to abort the attempt."""
+    verdict_every = eval_every if verdict_every is None else verdict_every
+    if verdict_every <= 0 or verdict_every % eval_every != 0:
         raise ValueError(
-            "start_iteration / start_num_weight_updates / boundary_cb "
-            "are resilience hooks of the verdict loop; pass "
-            "verdict_every=K to use them")
+            f"verdict_every={verdict_every} must be a positive multiple "
+            f"of eval_every={eval_every}")
     n_total = part.meas_global.num_poses
     num_meas = len(part.meas_global)
+    max_evals = -(-max_iters // eval_every)
+    certify_mode = getattr(params, "certify_mode", "off") \
+        if params is not None else "off"
 
     # Telemetry (dpgo_tpu.obs): resolved ONCE per solve.  When off, the
-    # eval program below is byte-identical to the uninstrumented driver —
+    # programs below are byte-identical to the uninstrumented driver —
     # zero events, zero registry calls, zero added transfers.  When on, the
     # extra per-eval scalars (GNC mu, inlier fraction, per-agent relative
-    # change) ride the SAME stacked readback the driver already pays for,
-    # so telemetry never adds a device->host round-trip to the hot loop.
+    # change) ride the SAME device-side history rows, fetched once per
+    # verdict boundary.
     obs_run = obs.get_run()
     telemetry = obs_run is not None
-
-    with trace.span("rbcd.programs"):
-        edges_g = edge_set_from_measurements(part.meas_global, dtype=dtype)
-        metrics_body = metrics_body_factory(telemetry) \
-            if metrics_body_factory is not None else None
-        central_metrics = jax.jit(metrics_body) if metrics_body is not None \
-            else _make_central_metrics(graph, edges_g, n_total, num_meas,
-                                       telemetry)
 
     robust_on = params is not None and \
         params.robust.cost_type != RobustCostType.L2
     accel_on = params is not None and params.acceleration
-
-    if segment is None:
-        # Legacy callers (step-only, or step + fused plain loop): synthesize
-        # the segment so ONE copy of the schedule-boundary arithmetic below
-        # serves every path.  Identical math — a segment is a flagged round
-        # plus plain rounds.
-        def segment(s, k, uw, rs):
-            s = step(s, uw, rs)
-            if k > 1:
-                if multi_step is not None:
-                    s = multi_step(s, k - 1)
-                else:
-                    for _ in range(k - 1):
-                        s = step(s, False, False)
-            return s
-
-    cost_hist, gn_hist = [], []
-    terminated_by = "max_iters"
-    it = 0
-    num_weight_updates = 0
 
     def _bounds(n_done, nwu):
         """Schedule arithmetic, shared with the flight-recorder replay —
@@ -2087,9 +2049,9 @@ def run_rbcd(
         from ..obs import health as health_mod
 
         # Numerical-health layer (obs.health): judges the same scalars the
-        # stacked readback below already carries — no extra transfers.
-        # The flight recorder is opt-in (FlightRecorder.attach); when one
-        # is attached, register the problem so its black box is
+        # history rows already carry — no extra transfers.  The flight
+        # recorder is opt-in (FlightRecorder.attach); when one is
+        # attached, register the problem so its black box is
         # self-contained and replayable.
         health_mon = health_mod.monitor_for(obs_run)
         flight_rec = getattr(obs_run, "recorder", None)
@@ -2110,6 +2072,21 @@ def run_rbcd(
             sel_mode=resolved_sel_mode(params)
             if params is not None else None,
             eval_every=eval_every)
+
+    with trace.span("rbcd.programs"):
+        edges_g = edge_set_from_measurements(part.meas_global, dtype=dtype)
+        verdict_step = make_verdict_program(
+            graph, edges_g, n_total, num_meas, telemetry,
+            grad_norm_tol=grad_norm_tol,
+            robust_params=params.robust if robust_on else None,
+            max_evals=max_evals,
+            health_cfg=health_mon.config if health_mon is not None else None,
+            metrics_body=metrics_body_factory(telemetry)
+            if metrics_body_factory is not None else None)
+        vs0 = init_verdict_state(max_evals, meta.num_robots, dtype,
+                                 telemetry)
+        epilogue = make_terminal_epilogue(graph, edges_g, n_total, num_meas,
+                                          meta, certify_mode=certify_mode)
 
     if telemetry:
         obs_run.event("solve_start", phase="solve",
@@ -2132,15 +2109,10 @@ def run_rbcd(
             g_inl = obs_run.gauge("gnc_inlier_fraction",
                                   "fraction of updatable LC edges at w>0.5")
 
-    host_fetches = 0  # sanctioned device->host syncs inside the loop
-
-    def _emit_eval(it_ev, vec, rounds, per_round, state=None, nwu=0):
+    def _emit_eval(it_ev, vec, rounds, per_round):
         """One eval's telemetry — gauges, metric events, flight-recorder
-        ring, health verdict — shared verbatim by the per-eval path and
-        the verdict path (which feeds it lazily-fetched history rows), so
-        both emit the identical event stream.  ``vec`` is a host-side
-        telemetry-width metrics row; ``state`` is passed only when an
-        exact snapshot is available at this eval (the per-eval path)."""
+        ring, health verdict — fed from a host-side telemetry-width
+        history row."""
         f, gn = float(vec[0]), float(vec[1])
         mu_v, inl, mean_w = (float(x) for x in vec[3:6])
         rel = vec[6:]
@@ -2170,8 +2142,7 @@ def run_rbcd(
             flight_rec.record_eval(
                 it_ev, {"cost": f, "grad_norm": gn,
                         "mu": mu_v, "inlier_frac": inl,
-                        "rel_change": rel},
-                state=state, num_weight_updates=nwu)
+                        "rel_change": rel})
         if health_mon is not None:
             health_mon.observe_solver(
                 it_ev, f, gn,
@@ -2181,168 +2152,8 @@ def run_rbcd(
                 stage=robust.gnc_stage_index(mu_v, params.robust)
                 if robust_on else None)
 
-    if verdict_every is not None:
-        return _run_verdict_loop(
-            state, graph, meta, segment, max_iters=max_iters,
-            grad_norm_tol=grad_norm_tol, eval_every=eval_every,
-            verdict_every=verdict_every, dtype=dtype, params=params,
-            edges_g=edges_g, n_total=n_total, num_meas=num_meas,
-            telemetry=telemetry, obs_run=obs_run, health_mon=health_mon,
-            flight_rec=flight_rec, emit_eval=_emit_eval,
-            bounds=_bounds, robust_on=robust_on,
-            metrics_body=metrics_body,
-            start_iteration=start_iteration,
-            start_nwu=start_num_weight_updates,
-            boundary_cb=boundary_cb)
-
-    # Pipelined driver: advance to each eval boundary, ENQUEUE the metrics
-    # program, dispatch one speculative segment past the boundary, and only
-    # then fetch the metrics — the device works through the speculation
-    # while the readback round-trip (the dominant host cost between evals)
-    # is in flight.  Flags are host-deterministic functions of the
-    # round index, so speculation never changes which rounds are flagged;
-    # a termination at the boundary simply discards the speculative state.
-    with _crash_dump_scope(flight_rec):
-        spec = None  # (state, it, uw) one segment past the last eval boundary
-        t_solve0 = t_window = time.perf_counter()
-        it_window = 0
-        while it < max_iters:
-            target = min(((it // eval_every) + 1) * eval_every, max_iters)
-            if spec is not None:
-                # A spec can only be pending at the top of an outer iteration
-                # (set at the previous eval boundary, exactly one segment ahead).
-                state, it, uw = spec
-                num_weight_updates += int(uw)
-                spec = None
-            while it < target:
-                uw, rs, end = _bounds(it, num_weight_updates)
-                num_weight_updates += int(uw)
-                state = segment(state, end - it, uw, rs)
-                it = end
-            fut = central_metrics(state.X, state.weights, state.ready,
-                                  state.mu, state.rel_change)
-            if it < max_iters:
-                uw, rs, end = _bounds(it, num_weight_updates)
-                spec = (segment(state, end - it, uw, rs), end, uw)
-            # THE sanctioned readback seam: the one stacked device->host
-            # fetch per eval.  Its span shows how much of the round-trip
-            # stayed hidden behind the speculative segment.
-            with trace.span("rbcd.fetch", phase="eval",
-                            labels={"what": "eval"}, iteration=it):
-                # dpgolint: disable=DPG003 -- sanctioned seam
-                vec = _host_fetch(fut)
-            host_fetches += 1
-            f, gn, consensus = vec[:3]
-            cost_hist.append(float(f))
-            gn_hist.append(float(gn))
-            if telemetry:
-                # The fetch above already materialized everything this block
-                # reads — host-side bookkeeping only from here.
-                now = time.perf_counter()
-                dt, t_window = now - t_window, now
-                rounds = max(it - it_window, 1)
-                it_window = it
-                _emit_eval(it, vec, rounds, dt / rounds, state=state,
-                           nwu=num_weight_updates)
-            if float(gn) < grad_norm_tol:
-                terminated_by = "grad_norm"
-                break
-            if consensus > 0:
-                terminated_by = "consensus"
-                break
-
-    # Final assembly as one jitted program (eager, the gather + rounding
-    # chain is hundreds of per-op dispatches).  With a certificate mode
-    # on, the device eigensolve fuses into the same program and the whole
-    # epilogue is read back as ONE blocking fetch; with certification off the outputs stay lazy
-    # device arrays exactly as before.
-    certify_mode = getattr(params, "certify_mode", "off") \
-        if params is not None else "off"
-    epilogue = make_terminal_epilogue(graph, edges_g, n_total, num_meas,
-                                      meta, certify_mode=certify_mode)
-    fin = epilogue(state.X, state.weights, {})
-    certificate = None
-    if certify_mode != "off":
-        # THE terminal blocking read (epilogue + certificate payload) —
-        # paid once per solve, excluded from the in-loop sync-rate metric
-        # like the lazy finalize it replaces.
-        with trace.span("rbcd.fetch", labels={"what": "epilogue"}):
-            # dpgolint: disable=DPG003 -- sanctioned terminal epilogue fetch
-            fin = _host_fetch(fin)
-        certificate = _epilogue_certificate(fin, edges_g, params, dtype)
-    T, w_glob = fin["T"], fin["w_glob"]
-    if telemetry:
-        _emit_sync_rate(obs_run, host_fetches, it)
-        obs_run.event(
-            "solve_end", phase="solve", iterations=it,
-            terminated_by=terminated_by,
-            duration_s=time.perf_counter() - t_solve0,
-            cost=cost_hist[-1] if cost_hist else None,
-            grad_norm=gn_hist[-1] if gn_hist else None,
-            num_weight_updates=num_weight_updates)
-    return RBCDResult(T=T, X=state.X, cost_history=cost_hist,
-                      grad_norm_history=gn_hist, iterations=it,
-                      terminated_by=terminated_by, weights=w_glob,
-                      state=state, certificate=certificate)
-
-
-def _emit_sync_rate(obs_run, fetches: int, rounds: int) -> None:
-    """Record the measured in-loop host-sync rate: the readback-kill
-    metric (``host_syncs_per_100_rounds``; lower is better, gated by
-    ``obs.regress``).  Counts only the driver-loop fetches through the
-    ``_host_fetch`` seam — the terminal finalize transfer is excluded, as
-    it is paid once per solve regardless of loop design."""
-    rate = 100.0 * fetches / max(rounds, 1)
-    obs_run.gauge("host_syncs_per_100_rounds",
-                  "driver-loop device->host fetches per 100 RBCD rounds"
-                  ).set(rate)
-    obs_run.metric("host_syncs_per_100_rounds", rate, phase="solve",
-                   fetches=fetches, rounds=rounds)
-
-
-def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
-                      grad_norm_tol, eval_every, verdict_every, dtype,
-                      params, edges_g, n_total, num_meas, telemetry,
-                      obs_run, health_mon, flight_rec, emit_eval, bounds,
-                      robust_on, metrics_body=None, start_iteration=0,
-                      start_nwu=0, boundary_cb=None):
-    """Body of ``run_rbcd``'s device-resident mode (see its docstring).
-
-    Per verdict boundary (every K rounds): dispatch the schedule segments
-    and the fused verdict evals, ENQUEUE the next boundary's work (depth-1
-    speculation, so the word fetch's round-trip hides behind device
-    execution), then fetch ONE packed int32.  The full per-eval history is
-    fetched lazily — per boundary with telemetry on (feeding the identical
-    gauge/event/health/recorder calls as the per-eval path), once at
-    termination otherwise.
-
-    Resumption (``start_iteration``/``start_nwu``) re-enters at an
-    absolute round index: every schedule quantity below is already a pure
-    function of it, so the flag sequence is identical to an uninterrupted
-    run's.  A resumed attempt gets a fresh verdict state — anomaly
-    latches clear, and its history rows cover only the resumed suffix."""
-    if verdict_every <= 0 or verdict_every % eval_every != 0:
-        raise ValueError(
-            f"verdict_every={verdict_every} must be a positive multiple "
-            f"of eval_every={eval_every}")
-    max_evals = -(-max_iters // eval_every)
-    certify_mode = getattr(params, "certify_mode", "off") \
-        if params is not None else "off"
-    with trace.span("rbcd.programs"):
-        verdict_step = make_verdict_program(
-            graph, edges_g, n_total, num_meas, telemetry,
-            grad_norm_tol=grad_norm_tol,
-            robust_params=params.robust if robust_on else None,
-            max_evals=max_evals,
-            health_cfg=health_mon.config if health_mon is not None else None,
-            metrics_body=metrics_body)
-        vs0 = init_verdict_state(max_evals, meta.num_robots, dtype,
-                                 telemetry)
-        epilogue = make_terminal_epilogue(graph, edges_g, n_total, num_meas,
-                                          meta, certify_mode=certify_mode)
-
     eval_its: list[int] = []
-    fetches = 0
+    fetches = 0  # sanctioned device->host syncs inside the loop
 
     def advance(st, it, nwu, vs, target):
         """Enqueue segments + fused verdict evals up to ``target`` (no
@@ -2351,7 +2162,7 @@ def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
             while it < target:
                 ev_t = min(((it // eval_every) + 1) * eval_every, target)
                 while it < ev_t:
-                    uw, rs, end = bounds(it, nwu)
+                    uw, rs, end = _bounds(it, nwu)
                     nwu += int(uw)
                     st = segment(st, end - it, uw, rs)
                     it = end
@@ -2360,14 +2171,19 @@ def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
                 eval_its.append(it)
         return st, it, nwu, vs
 
+    # Per verdict boundary (every K rounds): dispatch the schedule segments
+    # and the fused verdict evals, ENQUEUE the next boundary's work
+    # (depth-1 speculation, so the word fetch's round-trip hides behind
+    # device execution), then fetch ONE packed int32.
     t_solve0 = t_window = time.perf_counter()
     it_window = int(start_iteration)
     fed = 0
+    snap_at = None  # evals fed when the flight recorder last snapshotted
     hist_rows = None
     terminated_by = "max_iters"
     n_keep = it_final = 0
     with _crash_dump_scope(flight_rec):
-        it, nwu, vs = int(start_iteration), int(start_nwu), vs0
+        it, nwu, vs = int(start_iteration), int(start_num_weight_updates), vs0
         bound = lambda i: min(((i // verdict_every) + 1) * verdict_every,
                               max_iters)
         state, it, nwu, vs = advance(state, it, nwu, vs, bound(it))
@@ -2411,8 +2227,7 @@ def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
                 # certify_mode="device"), the eval history, and the
                 # latched terminal indices — one pytree, one fetch.  The
                 # history leg replaces the recurring telemetry fetch at
-                # this boundary (same count); everything else replaced
-                # the old separate tail fetch + lazy finalize.
+                # this boundary (same count).
                 with trace.span("rbcd.enqueue", labels={"what": "epilogue"}):
                     fin = epilogue(
                         state_pre.X, state_pre.weights,
@@ -2441,17 +2256,21 @@ def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
                 for r in range(fed, feed_to):
                     rounds_r = eval_its[r] - (eval_its[r - 1] if r
                                               else int(start_iteration))
-                    emit_eval(eval_its[r], hist_rows[r], max(rounds_r, 1),
-                              per_round)
+                    _emit_eval(eval_its[r], hist_rows[r], max(rounds_r, 1),
+                               per_round)
                 fed = feed_to
-                if flight_rec is not None and not terminal:
-                    # Exact-state snapshot at the verdict boundary (the
-                    # K-cadence analog of record_eval's snapshot path).
-                    # hist_rows is already host-side (the lazy fetch).
+                if flight_rec is not None and not terminal and (
+                        snap_at is None
+                        or fed - snap_at >= flight_rec.snapshot_every):
+                    # Exact-state snapshot at the verdict boundary, on the
+                    # recorder's eval cadence (the first eval, then every
+                    # snapshot_every evals).  hist_rows is already
+                    # host-side (the lazy fetch).
                     rows_finite = np.isfinite(hist_rows[:feed_to]).all()
                     flight_rec.snapshot_state(
                         it_pre, state_pre, nwu_pre,
                         healthy=bool(rows_finite))
+                    snap_at = fed
             if terminal:
                 state = state_pre
                 break
@@ -2481,6 +2300,20 @@ def _run_verdict_loop(state, graph, meta, segment, *, max_iters,
                       grad_norm_history=gn_hist, iterations=it_final,
                       terminated_by=terminated_by, weights=w_glob,
                       state=state, certificate=certificate)
+
+
+def _emit_sync_rate(obs_run, fetches: int, rounds: int) -> None:
+    """Record the measured in-loop host-sync rate: the readback-kill
+    metric (``host_syncs_per_100_rounds``; lower is better, gated by
+    ``obs.regress``).  Counts only the driver-loop fetches through the
+    ``_host_fetch`` seam — the terminal epilogue transfer is excluded, as
+    it is paid once per solve regardless of loop design."""
+    rate = 100.0 * fetches / max(rounds, 1)
+    obs_run.gauge("host_syncs_per_100_rounds",
+                  "driver-loop device->host fetches per 100 RBCD rounds"
+                  ).set(rate)
+    obs_run.metric("host_syncs_per_100_rounds", rate, phase="solve",
+                   fetches=fetches, rounds=rounds)
 
 
 def initial_state_for(init: str, part: Partition, meta: GraphMeta,
@@ -2573,11 +2406,12 @@ def dispatch_prepared(
     state: RBCDState | None = None,
     verdict_every: int | None = None,
 ) -> RBCDResult:
-    """Solve dispatch for a prepared problem: build the step closures and
-    run the shared driver loop (``run_rbcd``).  ``state`` overrides the
-    fresh ``init_state`` — e.g. to resume from a snapshot.
-    ``verdict_every`` opts into the device-resident verdict loop (one
-    packed-word readback per K rounds — see ``run_rbcd``)."""
+    """Solve dispatch for a prepared problem: build the segment closure
+    and run the shared driver loop (``run_rbcd``).  ``state`` overrides
+    the fresh ``init_state`` — e.g. to resume from a snapshot.
+    ``verdict_every`` sets how often the host reads the verdict word (K
+    rounds, a multiple of ``eval_every``; None reads it at every eval —
+    see ``run_rbcd``)."""
     params = prob.params
     max_iters = params.max_num_iters if max_iters is None else max_iters
     if state is None and prob.X0 is None:
@@ -2585,9 +2419,6 @@ def dispatch_prepared(
             "prepared problem has no initial state — prepare with "
             "init=... or pass state=")
     graph, meta = prob.graph, prob.meta
-    step = lambda s, uw, rs: rbcd_step(s, graph, meta, params,
-                                       update_weights=uw, restart=rs)
-    multi = lambda s, k: rbcd_steps(s, graph, k, meta, params)
     seg = lambda s, k, uw, rs: rbcd_segment(s, graph, k, meta, params,
                                             first_update_weights=uw,
                                             first_restart=rs)
@@ -2595,9 +2426,8 @@ def dispatch_prepared(
         if state is None:
             with trace.span("rbcd.programs"):
                 state = init_state(graph, meta, prob.X0, params=params)
-        return run_rbcd(state, graph, meta, step, prob.part, max_iters,
+        return run_rbcd(state, graph, meta, seg, prob.part, max_iters,
                         grad_norm_tol, eval_every, prob.dtype, params=params,
-                        multi_step=multi, segment=seg,
                         verdict_every=verdict_every)
 
 

@@ -203,13 +203,13 @@ class FlightRecorder:
 
     def snapshot_state(self, iteration: int, state, num_weight_updates: int,
                        healthy: bool = True) -> None:
-        """Take one exact-state snapshot outside ``record_eval``'s
-        cadence — the verdict-loop driver (``models.rbcd``'s
-        ``verdict_every`` mode) snapshots at its K-round fetch boundaries,
-        where the live state is on hand, while the per-eval scalar rows
-        arrive separately through ``record_eval(state=None)`` from the
-        lazily-fetched device history.  ``iteration`` must be an eval
-        boundary present in the ring for the replay to align."""
+        """Take one exact-state snapshot outside ``record_eval`` — the
+        driver (``models.rbcd.run_rbcd``) snapshots at its verdict-word
+        fetch boundaries, where the live state is on hand, on this
+        recorder's ``snapshot_every`` eval cadence, while the per-eval
+        scalar rows arrive separately through ``record_eval(state=None)``
+        from the lazily-fetched device history.  ``iteration`` must be an
+        eval boundary present in the ring for the replay to align."""
         self._snapshot(iteration, state, num_weight_updates, bool(healthy))
         self._evals_since_snap = 0
 

@@ -784,20 +784,22 @@ def solve_rbcd_sharded(
     """Distributed solve over a device mesh — the deployment path of the
     framework (``models.rbcd.solve_rbcd`` is the single-device debug path).
     Shares the driver loop (``rbcd.run_rbcd``); only problem placement and
-    the step function differ.  ``exchange`` selects the pose-exchange
+    the segment function differ.  ``exchange`` selects the pose-exchange
     collective: ``"all_gather"`` (v1) or ``"ppermute"`` (one collective per
     ring offset that carries a cross-device edge — fewer hops than the
     all_gather ring when the device adjacency is near-chain).
 
-    ``verdict_every`` (K, a positive multiple of ``eval_every``) switches
-    the sharded driver to the DEVICE-RESIDENT verdict loop: the centralized
-    metrics trace under shard_map with their reductions as psums
-    (``make_sharded_metrics_body``), termination latches on device, and the
-    host reads back ONE replicated packed int32 per K rounds through the
-    same ``rbcd._host_fetch`` seam as the single-device loop — killing the
-    per-eval readback on the mesh path too.  ``overlap`` (default on)
-    software-pipelines the halo exchange inside the fused round loops
-    (``make_sharded_multi_step``); ``overlap="auto"`` runs a bounded
+    Termination is decided on the device, as on one device: the
+    centralized metrics trace under shard_map with their reductions as
+    psums (``make_sharded_metrics_body``), the verdict word latches the
+    terminal eval, and the host reads back ONE replicated packed int32
+    every ``verdict_every`` rounds (K, a positive multiple of
+    ``eval_every``; None reads it at every eval) through the same
+    ``rbcd._host_fetch`` seam as the single-device loop.
+
+    ``overlap`` (default on) software-pipelines the halo exchange inside
+    the fused round loops (``make_sharded_multi_step``);
+    ``overlap="auto"`` runs a bounded
     lockstep-vs-overlapped calibration on the sharded problem
     (``_resolve_overlap_auto``) and picks the winner, recording an
     ``overlap_decision`` event with the A/B walls and — with telemetry on
@@ -809,9 +811,9 @@ def solve_rbcd_sharded(
     histories with the tail's trajectory and re-finalizing the rounded
     trajectory from the polished iterate.
 
-    ``resilience`` (a ``resilience_mod.ResilienceConfig``, requires the
-    verdict loop) arms the pod-scale fault story: mesh-elastic
-    checkpoints at verdict boundaries, watchdog deadlines on every
+    ``resilience`` (a ``resilience_mod.ResilienceConfig``) arms the
+    pod-scale fault story: mesh-elastic checkpoints at verdict
+    boundaries, watchdog deadlines on every
     blocking fetch, and a supervisor that catches latched verdict
     anomalies and ``MeshFaultError``\\ s, rewinds to the last good
     checkpoint — on a smaller mesh after a device loss — and resumes at
@@ -820,9 +822,9 @@ def solve_rbcd_sharded(
     happened; its histories cover the final (resumed) attempt — a
     numerically-pinned suffix of the undisturbed run's.
 
-    ``boundary_cb(it, nwu, state, word, terminal)`` (requires the
-    verdict loop) is an external verdict-boundary hook that runs BEFORE
-    the resilience supervisor's own: the multihost lockstep
+    ``boundary_cb(it, nwu, state, word, terminal)`` is an external
+    verdict-boundary hook that runs BEFORE the resilience supervisor's
+    own: the multihost lockstep
     (``parallel.multihost``) rides it to cross-check the replicated
     verdict word across processes and surface a dead peer as
     ``MeshFaultError(kind="process_lost")``.  ``resume=True`` (requires
@@ -842,15 +844,6 @@ def solve_rbcd_sharded(
             f"contiguous blocks per device.  Pick num_robots as a "
             f"multiple of {mesh_size}, or build a smaller mesh "
             f"(make_mesh(n) with n dividing {num_robots}).")
-    if resilience is not None and verdict_every is None:
-        raise ValueError(
-            "resilience=ResilienceConfig(...) rides the verdict-boundary "
-            "contract (checkpoints at word-fetch boundaries); pass "
-            "verdict_every=K to use it")
-    if boundary_cb is not None and verdict_every is None:
-        raise ValueError(
-            "boundary_cb is a verdict-boundary hook; pass verdict_every=K "
-            "to use it")
     if resume and resilience is None:
         raise ValueError(
             "resume=True restores from the resilience checkpoint store; "
@@ -897,40 +890,26 @@ def solve_rbcd_sharded(
     n_total = part.meas_global.num_poses
     num_meas = len(part.meas_global)
     certify_mode = getattr(params, "certify_mode", "off")
-    edges_g = edge_set_from_measurements(part.meas_global, dtype=dtype) \
-        if (verdict_every is not None or certify_mode != "off") else None
+    edges_g = edge_set_from_measurements(part.meas_global, dtype=dtype)
 
     def _attempt(mesh_a, state_a, graph_a, start_it, start_nwu,
                  boundary_cb, injector):
-        """One driver entry on one mesh: build the compiled step/segment
-        wrappers for ``mesh_a`` and run ``rbcd.run_rbcd`` from the given
+        """One driver entry on one mesh: build the compiled segment
+        wrapper for ``mesh_a`` and run ``rbcd.run_rbcd`` from the given
         absolute round index.  The supervisor loop below re-invokes this
         after a rewind — possibly on a smaller mesh."""
         size_a = int(mesh_a.devices.size)
         shifts, plan = _exchange_plan(mesh_a, meta, graph_a, exchange)
-        sharded_step = make_sharded_step(mesh_a, meta, params, shifts, plan)
-        sharded_multi = make_sharded_multi_step(mesh_a, meta, params, shifts,
-                                                plan, overlap=overlap)
         sharded_seg = make_sharded_segment(mesh_a, meta, params, shifts,
                                            plan, overlap=overlap)
         if run is not None:
-            # Compile accounting with the bytes-per-flop roofline for
-            # every hot sharded program (devprof.profiled_program AOT-
-            # compiles once per static combo — same compile count as the
-            # plain jit path — and falls back to it on any probe
-            # failure).  Fence-guarded: telemetry off keeps the bare jit
-            # callables.
+            # Compile accounting with the bytes-per-flop roofline for the
+            # hot sharded program (devprof.profiled_program AOT-compiles
+            # once per static combo — same compile count as the plain jit
+            # path — and falls back to it on any probe failure).
+            # Fence-guarded: telemetry off keeps the bare jit callable.
             from ..obs import devprof
 
-            sharded_step = devprof.profiled_program(
-                run, sharded_step, key=f"sharded/{size_a}/step",
-                label="sharded_step", plane="sharded",
-                static_names=("update_weights", "restart"),
-                mesh_size=size_a)
-            sharded_multi = devprof.profiled_program(
-                run, sharded_multi, key=f"sharded/{size_a}/multi_step",
-                label="sharded_multi_step", plane="sharded",
-                mesh_size=size_a)
             sharded_seg = devprof.profiled_program(
                 run, sharded_seg, key=f"sharded/{size_a}/segment",
                 label="sharded_segment", plane="sharded",
@@ -940,29 +919,17 @@ def solve_rbcd_sharded(
             # Chaos seam (parallel.resilience): the injector counts
             # dispatched rounds and may poison a seeded public pose —
             # an async device op, never a host sync.
-            step = lambda s, uw, rs: sharded_step(
-                injector.before_dispatch(s, 1), graph_a,
-                update_weights=uw, restart=rs)
-            multi = lambda s, k: sharded_multi(
-                injector.before_dispatch(s, k), graph_a, k)
             seg = lambda s, k, uw, rs: sharded_seg(
                 injector.before_dispatch(s, k), graph_a, k,
                 update_weights=uw, restart=rs)
         else:
-            step = lambda s, uw, rs: sharded_step(s, graph_a,
-                                                  update_weights=uw,
-                                                  restart=rs)
-            multi = lambda s, k: sharded_multi(s, graph_a, k)
             seg = lambda s, k, uw, rs: sharded_seg(s, graph_a, k,
                                                    update_weights=uw,
                                                    restart=rs)
-        metrics_factory = None
-        if verdict_every is not None:
-            # The device-resident verdict loop under sharding: the same
-            # driver (run_rbcd -> _run_verdict_loop), with the stacked-
-            # metrics body traced inside shard_map, reductions as psums.
-            metrics_factory = lambda telemetry: make_sharded_metrics_body(
-                mesh_a, graph_a, edges_g, n_total, num_meas, telemetry)
+        # The shared driver (run_rbcd), with the stacked-metrics body
+        # traced inside shard_map, reductions as psums.
+        metrics_factory = lambda telemetry: make_sharded_metrics_body(
+            mesh_a, graph_a, edges_g, n_total, num_meas, telemetry)
         if run is not None:
             bytes_round = comm_bytes_per_round(
                 meta, size_a,
@@ -985,9 +952,8 @@ def solve_rbcd_sharded(
             # for the convergence regression gate (report --compare).
             run.set_fingerprint(solver="solve_rbcd_sharded",
                                 mesh_size=size_a, exchange=exchange)
-        return rbcd.run_rbcd(state_a, graph_a, meta, step, part, max_iters,
+        return rbcd.run_rbcd(state_a, graph_a, meta, seg, part, max_iters,
                              grad_norm_tol, eval_every, dtype, params=params,
-                             multi_step=multi, segment=seg,
                              verdict_every=verdict_every,
                              metrics_body_factory=metrics_factory,
                              start_iteration=start_it,

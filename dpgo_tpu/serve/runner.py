@@ -14,7 +14,7 @@ problem, so one executable per (bucket, pow2-width) serves every
 occupancy instead of one per exact batch size.
 
 Executables come from the caller's ``ExecutableCache`` keyed by the
-config fingerprint (``cache.problem_fingerprint``): segment, metrics, and
+config fingerprint (``cache.problem_fingerprint``): segment, verdict, and
 terminal-epilogue programs are each cached independently; with
 ``params.certify_mode="device"`` the epilogue program also computes the
 per-member dual-certificate payload so the certificate rides the batch's
@@ -25,27 +25,27 @@ dispatch window times itself into ``serve_dispatch_device_seconds``, and
 the stack/dispatch/slice stages emit spans under the server's per-batch
 ``dispatch`` span; with telemetry off none of that machinery exists.
 
-Termination mirrors ``run_rbcd``: per problem, the centralized gradient
-norm against ``grad_norm_tol`` or all-agents consensus; the batch keeps
-stepping until every member has terminated (a converged member's extra
-rounds only polish its iterate — cost is monotone under the plain
-schedule), with each member's history truncated at its own termination
-eval.
+Termination is ``run_rbcd``'s, decided on device by the same
+``rbcd._verdict_update`` (the verdict program is a ``vmap`` of the
+library's): per problem, the centralized gradient norm against
+``grad_norm_tol`` or all-agents consensus; the batch keeps stepping until
+every member has terminated (a converged member's extra rounds only
+polish its iterate — cost is monotone under the plain schedule), with
+each member's history truncated at its own termination eval.
 """
 
 from __future__ import annotations
 
 import time
+from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .. import obs
 from ..config import RobustCostType
 from ..models import rbcd
 from ..obs.trace import span
-from ..ops import manifold, quadratic
 from .bucketing import PaddedProblem
 from .cache import ExecutableCache, fingerprint_key, problem_fingerprint
 
@@ -74,58 +74,6 @@ def _make_segment_exec(meta: rbcd.GraphMeta, params):
         return jax.vmap(one)(state_b, graph_b)
 
     return jax.jit(seg, static_argnames=("uw", "rs"))
-
-
-def _make_metrics_exec(meta: rbcd.GraphMeta, n_total: int, num_meas: int):
-    def one(Xa, weights, ready, graph, eg):
-        Xg = rbcd.gather_to_global(Xa, graph, n_total)
-        egw = eg._replace(
-            weight=rbcd.global_weights(weights, graph, num_meas))
-        f = quadratic.cost(Xg, egw)
-        g = manifold.rgrad(Xg, quadratic.egrad(Xg, egw))
-        return jnp.stack(
-            [f, manifold.norm(g), jnp.all(ready).astype(f.dtype)])
-
-    return jax.jit(jax.vmap(one))
-
-
-def _make_verdict_exec(meta: rbcd.GraphMeta, n_total: int, num_meas: int,
-                       grad_norm_tol: float):
-    """Batched fused-eval program of the verdict mode: per problem, the
-    centralized metrics, the convergence test, and a non-finite sentinel
-    fold into a packed per-problem verdict word (``rbcd``'s word layout),
-    the terminal eval latches on device, and the metric row appends to a
-    device-side history — so the host reads back ONE ``[B]`` int32 vector
-    per K rounds instead of the ``[B, 3]`` float stack per eval."""
-
-    def one(Xa, weights, ready, graph, eg, iteration,
-            word, term_eval, term_it, eval_idx, hist):
-        Xg = rbcd.gather_to_global(Xa, graph, n_total)
-        egw = eg._replace(
-            weight=rbcd.global_weights(weights, graph, num_meas))
-        f = quadratic.cost(Xg, egw)
-        g = manifold.rgrad(Xg, quadratic.egrad(Xg, egw))
-        gn = manifold.norm(g)
-        consensus = jnp.all(ready).astype(f.dtype)
-        vec = jnp.stack([f, gn, consensus])
-        status_now = jnp.where(
-            gn < grad_norm_tol, rbcd.VERDICT_GRAD_NORM,
-            jnp.where(consensus > 0, rbcd.VERDICT_CONSENSUS,
-                      rbcd.VERDICT_RUNNING)).astype(jnp.int32)
-        status = jnp.where(term_eval >= 0, word & 7, status_now)
-        finite = jnp.isfinite(f) & jnp.isfinite(gn)
-        anom = jnp.maximum((word >> 3) & 7,
-                           jnp.where(finite, 0, rbcd.ANOMALY_NON_FINITE))
-        first = (term_eval < 0) & (status != rbcd.VERDICT_RUNNING)
-        term_eval = jnp.where(first, eval_idx, term_eval)
-        term_it = jnp.where(first, iteration.astype(jnp.int32), term_it)
-        hist = jax.lax.dynamic_update_slice(
-            hist, vec[None, :].astype(hist.dtype),
-            (eval_idx, jnp.zeros((), eval_idx.dtype)))
-        return ((status | (anom << 3)).astype(jnp.int32),
-                term_eval, term_it, eval_idx + 1, hist)
-
-    return jax.jit(jax.vmap(one))
 
 
 def _make_epilogue_exec(meta: rbcd.GraphMeta, n_total: int, num_meas: int,
@@ -201,30 +149,30 @@ def run_bucket(padded: list[PaddedProblem], cache: ExecutableCache,
     counts), and a dict of batch statistics (rounds, evals, batch width,
     occupancy) for the serving metrics.
 
-    ``verdict_every`` (a positive multiple of ``eval_every``) switches
-    the batch to the device-resident verdict loop: per-problem
-    termination latches on device (``_make_verdict_exec``) and the host
-    reads back one packed ``[B]`` int32 verdict vector per K rounds per
-    bucket, with the per-eval histories fetched once at the end.  A
-    member that terminates mid-window runs up to ``K - eval_every``
-    extra polish rounds (monotone under the plain schedule, like the
-    legacy batch's wait-for-the-batch behavior); its reported history
-    and round count are truncated at its latched terminal eval.
+    Termination is the library's: per problem, the batched verdict
+    program — a ``vmap`` of ``rbcd._verdict_step_jit``, so the one
+    ``rbcd._verdict_update`` — latches the terminal eval on device, and
+    the host reads back one packed ``[B]`` int32 verdict vector every
+    ``verdict_every`` rounds (K, a positive multiple of ``eval_every``;
+    None reads it at every eval), with the per-eval histories fetched
+    once at the end.  A member that terminates mid-window runs up to
+    ``K - eval_every`` extra polish rounds (monotone under the plain
+    schedule, like waiting for the rest of the batch); its reported
+    history and round count are truncated at its latched terminal eval.
 
     ``session_cb(iteration, states)`` — the crash-recovery hook
-    (``serve.session``): called every ``session_every`` eval boundaries
-    (and at the verdict-mode K boundaries) with the per-problem sliced
-    solver states, so a server can persist resumable snapshots while the
-    batch is in flight.  A member problem carrying ``state0`` resumes
-    from that exact state instead of its ``X0`` init.
+    (``serve.session``): called every ``session_every`` verdict
+    boundaries with the per-problem sliced solver states, so a server
+    can persist resumable snapshots while the batch is in flight.  A
+    member problem carrying ``state0`` resumes from that exact state
+    instead of its ``X0`` init.
 
     ``should_stop()`` — the live-migration hook (``serve.fleet``):
-    polled at eval/verdict boundaries, AFTER the boundary's
-    ``session_cb`` snapshot lands (when one is due it is forced, so a
-    stopping batch always leaves a resume point).  A True return breaks
-    the loop early; the partial results return as usual and ``info``
-    carries ``interrupted=True`` so the server can evacuate instead of
-    replying."""
+    polled at every verdict boundary.  A True return forces that
+    boundary's ``session_cb`` snapshot (so a stopping batch always leaves
+    a resume point) and breaks the loop early; the partial results
+    return as usual and ``info`` carries ``interrupted=True`` so the
+    server can evacuate instead of replying."""
     if not padded:
         return [], {"rounds": 0, "evals": 0, "batch": 0, "occupancy": 0.0,
                     "interrupted": False}
@@ -239,6 +187,11 @@ def run_bucket(padded: list[PaddedProblem], cache: ExecutableCache,
                 "bucketing must never mix incompatible shapes "
                 f"({p.shape} vs {shape})")
     max_iters = params.max_num_iters if max_iters is None else max_iters
+    verdict_every = eval_every if verdict_every is None else verdict_every
+    if verdict_every <= 0 or verdict_every % eval_every != 0:
+        raise ValueError(
+            f"verdict_every={verdict_every} must be a positive "
+            f"multiple of eval_every={eval_every}")
 
     B_real = len(padded)
     B = _next_pow2(B_real)
@@ -269,9 +222,18 @@ def run_bucket(padded: list[PaddedProblem], cache: ExecutableCache,
         cache, problem_fingerprint(meta, params, dtype, shape, B, "segment"),
         lambda: _make_segment_exec(meta, params),
         static_names=("uw", "rs"))
-    met = _cached_exec(
-        cache, problem_fingerprint(meta, params, dtype, shape, B, "metrics"),
-        lambda: _make_metrics_exec(meta, shape.n_total, shape.num_meas))
+    # The library's verdict step per member; the gate is an argument, so
+    # every tolerance shares one executable.  The history holds a pow2
+    # number of eval rows, so resumed budgets mostly reuse one program.
+    max_evals = _next_pow2(-(-max_iters // eval_every))
+    gnc, health = rbcd._verdict_statics(None, None)
+    one_verdict = partial(rbcd._verdict_step_jit, n_total=shape.n_total,
+                          num_meas=shape.num_meas, telemetry=False,
+                          gnc=gnc, health=health)
+    vex = _cached_exec(
+        cache, problem_fingerprint(meta, params, dtype, shape, B,
+                                   f"verdict:{max_evals}"),
+        lambda: jax.jit(jax.vmap(one_verdict, in_axes=(0,) * 9 + (None,))))
     certify_mode = getattr(params, "certify_mode", "off")
     fin = _cached_exec(
         cache, problem_fingerprint(meta, params, dtype, shape, B,
@@ -281,163 +243,82 @@ def run_bucket(padded: list[PaddedProblem], cache: ExecutableCache,
 
     robust_on = params.robust.cost_type != RobustCostType.L2
     accel_on = params.acceleration
+    vs = jax.tree.map(lambda a: jnp.broadcast_to(a, (B,) + a.shape),
+                      rbcd.init_verdict_state(max_evals, meta.num_robots,
+                                              dtype, False))
+    tol = jnp.asarray(grad_norm_tol)
+    run = obs.get_run()
 
     it = 0
     nwu = 0
-    evals = 0
-    done = [False] * B_real
-    cost_hist = [[] for _ in range(B_real)]
-    gn_hist = [[] for _ in range(B_real)]
-    term = ["max_iters"] * B_real
-    iters = [max_iters] * B_real
+    evals = boundaries = 0
     interrupted = False
-    run = obs.get_run()
-
-    if verdict_every is not None:
-        if verdict_every <= 0 or verdict_every % eval_every != 0:
-            raise ValueError(
-                f"verdict_every={verdict_every} must be a positive "
-                f"multiple of eval_every={eval_every}")
-        vex = _cached_exec(
-            cache, problem_fingerprint(meta, params, dtype, shape, B,
-                                       f"verdict{grad_norm_tol}"),
-            lambda: _make_verdict_exec(meta, shape.n_total, shape.num_meas,
-                                       grad_norm_tol))
-        max_evals = -(-max_iters // eval_every)
-        word = jnp.zeros((B,), jnp.int32)
-        term_eval = jnp.full((B,), -1, jnp.int32)
-        term_it = jnp.full((B,), -1, jnp.int32)
-        eidx = jnp.zeros((B,), jnp.int32)
-        hist = jnp.zeros((B, max_evals, 3), jnp.dtype(dtype))
-        eval_its: list[int] = []
-        while True:
-            vtarget = min(((it // verdict_every) + 1) * verdict_every,
-                          max_iters)
-            t_d0 = time.monotonic() if run is not None else 0.0
-            with span("device_dispatch", phase="serve", batch=B,
-                      verdict=True):
-                while it < vtarget:
-                    target = min(((it // eval_every) + 1) * eval_every,
-                                 vtarget)
-                    while it < target:
-                        uw, rs, end = rbcd.schedule_bounds(
-                            it, nwu, max_iters=max_iters,
-                            eval_every=eval_every, params=params,
-                            robust_on=robust_on, accel_on=accel_on)
-                        nwu += int(uw)
-                        state_b = seg(state_b, graph_b, end - it,
-                                      uw=uw, rs=rs)
-                        it = end
-                    word, term_eval, term_it, eidx, hist = vex(
-                        state_b.X, state_b.weights, state_b.ready,
-                        graph_b, eg_b, state_b.iteration,
-                        word, term_eval, term_it, eidx, hist)
-                    evals += 1
-                    eval_its.append(it)
-                # The batch's one readback per K rounds: the packed
-                # per-problem verdict vector.
-                # dpgolint: disable=DPG003 -- sanctioned verdict fetch
-                wv = rbcd._host_fetch(word)
-            if run is not None:
-                dt = time.monotonic() - t_d0
-                run.gauge("serve_dispatch_device_seconds",
-                          "wall-clock of the last batched dispatch window "
-                          "(segment launches through metrics readback)",
-                          unit="s").set(dt)
-                run.counter("serve_device_time_seconds_total",
-                            "cumulative batched-dispatch wall-clock",
-                            unit="s").inc(dt)
-            if session_cb is not None:
-                # Snapshot at the verdict boundary: the live batch state is
-                # on hand and the window's segments have already retired.
-                session_cb(it, _slice_states(state_b, B_real))
-            if should_stop is not None and should_stop():
-                # Stop AFTER the boundary snapshot: the batch leaves a
-                # resume point at exactly this iteration.
-                interrupted = True
-                break
-            all_terminal = ((wv & 7) != rbcd.VERDICT_RUNNING).all()
-            if it >= max_iters or bool(all_terminal):
-                break
-
-    while verdict_every is None and it < max_iters and not all(done) \
-            and not interrupted:
-        target = min(((it // eval_every) + 1) * eval_every, max_iters)
+    while True:
+        vtarget = min(((it // verdict_every) + 1) * verdict_every, max_iters)
         t_d0 = time.monotonic() if run is not None else 0.0
-        with span("device_dispatch", phase="serve", batch=B):
-            while it < target:
-                uw, rs, end = rbcd.schedule_bounds(
-                    it, nwu, max_iters=max_iters, eval_every=eval_every,
-                    params=params, robust_on=robust_on, accel_on=accel_on)
-                nwu += int(uw)
-                state_b = seg(state_b, graph_b, end - it, uw=uw, rs=rs)
-                it = end
-            # The metrics readback is the batch's existing sync point —
-            # timing to here measures dispatch -> materialized without
-            # adding a transfer or a block_until_ready.
-            # dpgolint: disable=DPG003 -- sanctioned seam: the batch's one
-            vec = np.asarray(met(state_b.X, state_b.weights, state_b.ready,
-                                 graph_b, eg_b))  # metrics fetch per eval
+        with span("device_dispatch", phase="serve", batch=B, verdict=True):
+            while it < vtarget:
+                target = min(((it // eval_every) + 1) * eval_every, vtarget)
+                while it < target:
+                    uw, rs, end = rbcd.schedule_bounds(
+                        it, nwu, max_iters=max_iters, eval_every=eval_every,
+                        params=params, robust_on=robust_on,
+                        accel_on=accel_on)
+                    nwu += int(uw)
+                    state_b = seg(state_b, graph_b, end - it, uw=uw, rs=rs)
+                    it = end
+                vs = vex(graph_b, eg_b, state_b.X, state_b.weights,
+                         state_b.ready, state_b.mu, state_b.rel_change,
+                         state_b.iteration, vs, tol)
+                evals += 1
+            # The batch's one readback per K rounds: the packed
+            # per-problem verdict vector.
+            # dpgolint: disable=DPG003 -- sanctioned verdict fetch
+            wv = rbcd._host_fetch(vs.word)
         if run is not None:
             dt = time.monotonic() - t_d0
             run.gauge("serve_dispatch_device_seconds",
                       "wall-clock of the last batched dispatch window "
-                      "(segment launches through metrics readback)",
+                      "(segment launches through verdict readback)",
                       unit="s").set(dt)
             run.counter("serve_device_time_seconds_total",
                         "cumulative batched-dispatch wall-clock",
                         unit="s").inc(dt)
-        evals += 1
+        boundaries += 1
         stop = should_stop is not None and should_stop()
         if session_cb is not None and (
-                stop or evals % max(int(session_every), 1) == 0):
+                stop or boundaries % max(int(session_every), 1) == 0):
             # A stopping batch forces the boundary snapshot even when the
             # cadence would skip it — migration needs the resume point.
             session_cb(it, _slice_states(state_b, B_real))
         if stop:
             interrupted = True
-        for b in range(B_real):
-            if done[b]:
-                continue
-            f, gn, consensus = vec[b]
-            cost_hist[b].append(float(f))
-            gn_hist[b].append(float(gn))
-            if float(gn) < grad_norm_tol:
-                done[b], term[b], iters[b] = True, "grad_norm", it
-            elif consensus > 0:
-                done[b], term[b], iters[b] = True, "consensus", it
+            break
+        all_terminal = ((wv & 7) != rbcd.VERDICT_RUNNING).all()
+        if it >= max_iters or bool(all_terminal):
+            break
 
     with span("slice", phase="serve", batch=B, certify=certify_mode):
         # The batch's ONE terminal blocking read: rounded trajectories,
-        # collapsed weights, the raw batch iterate, the verdict mode's
-        # device-side histories + latched indices, and (certify on) the
-        # per-member certificate payload — a single fused pytree fetch
-        # through the sanctioned seam.
+        # collapsed weights, the raw batch iterate, the device-side
+        # histories + latched indices, and (certify on) the per-member
+        # certificate payload — a single fused pytree fetch through the
+        # sanctioned seam.
         ep = {"fin": fin(state_b.X, state_b.weights, graph_b, eg_b),
-              "X": state_b.X}
-        if verdict_every is not None:
-            ep["hist"] = hist
-            ep["te"] = jnp.stack([term_eval, term_it])
+              "X": state_b.X, "hist": vs.hist,
+              "te": jnp.stack([vs.term_eval, vs.term_it])}
         # dpgolint: disable=DPG003 -- sanctioned terminal epilogue fetch
         ep = rbcd._host_fetch(ep)
-    if verdict_every is not None:
-        hist_h, te_h = ep["hist"], ep["te"]
-        for b in range(B_real):
-            te, ti = int(te_h[0, b]), int(te_h[1, b])
-            status = int(wv[b]) & 7
-            if te >= 0:
-                n_keep = te + 1
-                iters[b] = ti
-                term[b] = rbcd._VERDICT_STATUS.get(status, "max_iters")
-            else:
-                n_keep = len(eval_its)
-                iters[b] = it
-                term[b] = "max_iters"
-            cost_hist[b] = [float(hist_h[b, r, 0]) for r in range(n_keep)]
-            gn_hist[b] = [float(hist_h[b, r, 1]) for r in range(n_keep)]
+    hist_h, te_h = ep["hist"], ep["te"]
     T_b, w_b, X_b = ep["fin"]["T"], ep["fin"]["w"], ep["X"]
     results = []
     for b, p in enumerate(padded):
+        te, ti = int(te_h[0, b]), int(te_h[1, b])
+        if te >= 0:
+            n_keep, iters = te + 1, ti
+            term = rbcd._VERDICT_STATUS.get(int(wv[b]) & 7, "max_iters")
+        else:
+            n_keep, iters, term = evals, it, "max_iters"
         certificate = None
         if certify_mode != "off":
             # Host decision per member on the already-fetched payload —
@@ -451,10 +332,11 @@ def run_bucket(padded: list[PaddedProblem], cache: ExecutableCache,
         results.append(rbcd.RBCDResult(
             T=jnp.asarray(T_b[b, :p.prob.n_total]),
             X=jnp.asarray(X_b[b, :, :p.prob.meta.n_max]),
-            cost_history=cost_hist[b],
-            grad_norm_history=gn_hist[b],
-            iterations=iters[b],
-            terminated_by=term[b],
+            cost_history=[float(hist_h[b, r, 0]) for r in range(n_keep)],
+            grad_norm_history=[float(hist_h[b, r, 1])
+                               for r in range(n_keep)],
+            iterations=iters,
+            terminated_by=term,
             weights=jnp.asarray(w_b[b, :p.prob.num_meas]),
             certificate=certificate,
         ))

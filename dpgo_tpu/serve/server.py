@@ -255,7 +255,10 @@ class SolveServer:
     ``/healthz`` reports ``draining`` until shutdown completes).  With a
     ``session_store``, session-tagged requests survive worker deaths: the
     supervisor re-admits them from their last snapshot and the reply
-    carries ``recovered=True``."""
+    carries ``recovered=True``.  Each dispatched bucket runs
+    ``runner.run_bucket``: termination is decided on the device, and
+    ``verdict_every`` sets how often the host reads the bucket's verdict
+    vector (every eval by default)."""
 
     def __init__(self, max_batch: int = 8, max_queue: int = 64,
                  batch_window_s: float = 0.005,
@@ -282,17 +285,16 @@ class SolveServer:
         self.tenant_quota = tenant_quota
         self.quantum = int(quantum)
         self.init = init
-        #: Device-resident termination for dispatched buckets: one packed
-        #: [B] verdict-vector readback per this many rounds instead of the
-        #: per-eval float stack (``runner.run_bucket``'s verdict mode).
-        #: Requests whose ``eval_every`` does not divide it dispatch on
-        #: the legacy per-eval loop.  None = legacy everywhere.
+        #: How often a dispatched bucket reads back its packed [B]
+        #: verdict vector (``runner.run_bucket``): every this many rounds.
+        #: None, or a value the request's ``eval_every`` does not divide,
+        #: reads it at every eval.
         self.verdict_every = verdict_every
         #: One ``ServeSLO`` for every tenant, or a per-tenant dict (the
         #: ``"default"`` key, when present, covers unlisted tenants).
         self.slo = slo
         #: Crash-recovery session store (``serve.session``): session-tagged
-        #: requests snapshot every ``session_every`` solve boundaries and
+        #: requests snapshot every ``session_every`` verdict boundaries and
         #: are re-admitted from their last snapshot when the worker dies.
         #: A string is treated as the store's root directory.
         self.session_store = SessionStore(session_store) \
@@ -472,9 +474,9 @@ class SolveServer:
 
     def warm(self, requests: list[SolveRequest]) -> int:
         """Warm pool: run representative requests through prepare -> pad ->
-        batched dispatch at ``max_iters=1``, so their buckets' executables
-        are compiled and cached before real traffic.  Returns the number
-        of distinct buckets warmed."""
+        batched dispatch, stopped at the first verdict boundary, so their
+        buckets' executables are compiled and cached before real traffic.
+        Returns the number of distinct buckets warmed."""
         groups: dict[str, list] = {}
         for req in requests:
             padded, key, _ = self._prepare(req)
@@ -482,9 +484,10 @@ class SolveServer:
         for members in groups.values():
             padded_list = [p for p, _ in members][:self.max_batch]
             req0 = members[0][1]
-            run_bucket(padded_list, self.cache, max_iters=1,
+            run_bucket(padded_list, self.cache, max_iters=req0.max_iters,
                        grad_norm_tol=req0.grad_norm_tol,
-                       eval_every=1)
+                       eval_every=req0.eval_every,
+                       should_stop=lambda: True)
         run = obs.get_run()
         if run is not None:
             run.event("serve_warm", phase="serve", buckets=len(groups),
@@ -918,7 +921,7 @@ class SolveServer:
         try:
             ve = self.verdict_every
             if ve is not None and ve % max(req0.eval_every, 1) != 0:
-                ve = None  # incompatible cadence: legacy per-eval loop
+                ve = None  # incompatible cadence: read at every eval
             max_iters = req0.max_iters
             resume0 = tickets[0]._resumed_from
             if resume0:

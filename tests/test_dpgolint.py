@@ -546,7 +546,7 @@ def test_seeded_verdict_loop_sync_violations(tmp_path):
     ``_host_fetch`` call seeded into the verdict hot loop and (b) the
     same seeded into ``run_bucket``'s dispatch loop must be flagged by
     DPG003 via the configured ``sync_calls`` seam list, with file:line."""
-    # (a) models/rbcd.py: unsanctioned extra fetch in _run_verdict_loop.
+    # (a) models/rbcd.py: unsanctioned extra fetch in run_rbcd's loop.
     mdir = tmp_path / "dpgo_tpu" / "models"
     mdir.mkdir(parents=True)
     src = (REPO / "dpgo_tpu" / "models" / "rbcd.py").read_text()
@@ -568,9 +568,9 @@ def test_seeded_verdict_loop_sync_violations(tmp_path):
     sdir.mkdir(parents=True)
     rsrc = (REPO / "dpgo_tpu" / "serve" / "runner.py").read_text()
     rbad = rsrc.replace(
-        "            all_terminal = ",
-        "            _dbg = rbcd._host_fetch(hist)\n"
-        "            all_terminal = ")
+        "        all_terminal = ",
+        "        _dbg = rbcd._host_fetch(vs.hist)\n"
+        "        all_terminal = ")
     assert rbad != rsrc
     (sdir / "runner.py").write_text(rbad)
     findings = run_lint([str(tmp_path / "b" / "dpgo_tpu")],

@@ -110,15 +110,31 @@ def test_resilience_config_validation(tmp_path):
     assert cfg.resolve_store().keep == 4
 
 
-def test_resilience_requires_verdict_loop(tmp_path):
-    """Resilience rides the verdict-boundary contract: asking for it on
-    the per-eval driver is a config error, named as such."""
+def test_resilience_runs_at_the_default_cadence(tmp_path):
+    """Resilience rides the verdict boundaries, which the default cadence
+    puts at every eval: with ``verdict_every`` omitted the supervisor
+    checkpoints at each non-terminal eval boundary, and the solve equals
+    an explicit ``verdict_every=eval_every`` one."""
     meas = _noisy(3, n=24, num_lc=6, noise=0.01)
-    with pytest.raises(ValueError, match="verdict_every"):
-        solve_rbcd_sharded(
-            meas, num_robots=8, mesh=make_mesh(1), params=_PARAMS,
-            max_iters=4,
-            resilience=ResilienceConfig(checkpoint_dir=str(tmp_path)))
+    kw = dict(num_robots=8, mesh=make_mesh(1), params=_PARAMS, max_iters=8,
+              eval_every=2, grad_norm_tol=0.0)
+    seen = []
+    res = solve_rbcd_sharded(
+        meas, boundary_cb=lambda it, *_: seen.append(it),
+        resilience=ResilienceConfig(checkpoint_dir=str(tmp_path / "a")),
+        **kw)
+    ref = solve_rbcd_sharded(
+        meas, verdict_every=2,
+        resilience=ResilienceConfig(checkpoint_dir=str(tmp_path / "b")),
+        **kw)
+    assert seen == [2, 4, 6, 8]
+    assert res.resilience["checkpoints"] == 3
+    snap = SessionStore(str(tmp_path / "a")).load_newest("sharded-solve")
+    assert int(snap.iteration) == 6
+    assert (res.iterations, res.terminated_by) == \
+        (ref.iterations, ref.terminated_by)
+    assert res.cost_history == ref.cost_history
+    assert np.array_equal(np.asarray(res.X), np.asarray(ref.X))
 
 
 def test_shrink_mesh_size_respects_divisibility():

@@ -95,9 +95,13 @@ def test_new_pose_count_compiles_its_own_programs(three_solves):
     assert any("_epilogue_jit" in p for p in grown), grown
 
 
-def test_verdict_update_shared_by_both_paths(monkeypatch):
-    """The single-device program and the ``metrics_body=`` closure trace
-    the one ``_verdict_update``, and give bitwise the same states."""
+@pytest.mark.parametrize("path", ["closure", "batched"])
+def test_verdict_update_shared_by_both_paths(monkeypatch, path):
+    """The single-device program, the ``metrics_body=`` closure and the
+    serving plane's batched program all trace the one
+    ``_verdict_update``: the closure gives bitwise the library's states,
+    and ``run_bucket``'s history rows are bitwise the library program's
+    on the same states."""
     calls = []
     real = rbcd._verdict_update
 
@@ -109,6 +113,9 @@ def test_verdict_update_shared_by_both_paths(monkeypatch):
     jax.clear_caches()
     meas = _city(60, seed=3)
     params = AgentParams(d=2, r=3, num_robots=3, rel_change_tol=0.0)
+    if path == "batched":
+        _check_batched_rows(meas, params, calls)
+        return
     part = partition_contiguous(meas, 3)
     graph, meta = rbcd.build_graph(part, params.r, jnp.float64,
                                    sel_mode=rbcd.resolved_sel_mode(params))
@@ -139,6 +146,33 @@ def test_verdict_update_shared_by_both_paths(monkeypatch):
     for a, b in zip(jax.tree.leaves(vs_a), jax.tree.leaves(vs_b)):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
     assert int(np.asarray(vs_a.eval_idx)) == 3
+
+
+def _check_batched_rows(meas, params, calls):
+    from dpgo_tpu.serve import (ExecutableCache, bucket_shape_of,
+                                pad_problem, run_bucket)
+
+    prob = rbcd.prepare_problem(meas, 3, params=params, dtype=jnp.float64,
+                                init=None, pallas_sel=False)
+    shape = bucket_shape_of(prob, 64)
+    p = pad_problem(prob, shape)
+    states = []
+    (res,), _ = run_bucket(
+        [p], ExecutableCache(), max_iters=6, grad_norm_tol=1e-12,
+        eval_every=2, session_cb=lambda _it, sts: states.append(sts[0]))
+    assert calls == [None]  # traced once, with the batched statics
+    single = rbcd.make_verdict_program(
+        p.graph, p.edges_g, shape.n_total, shape.num_meas, False,
+        grad_norm_tol=1e-12, robust_params=None, max_evals=3)
+    vs = rbcd.init_verdict_state(3, p.meta.num_robots, jnp.float64, False)
+    for st in states:
+        vs = single(st.X, st.weights, st.ready, st.mu, st.rel_change,
+                    st.iteration, vs)
+    hist = np.asarray(vs.hist)
+    assert len(states) == len(res.cost_history) == 3
+    assert hist[:, 0].tobytes() == np.asarray(res.cost_history).tobytes()
+    assert hist[:, 1].tobytes() == \
+        np.asarray(res.grad_norm_history).tobytes()
 
 
 def test_gradient_tolerance_is_traced():

@@ -215,9 +215,10 @@ def test_rbcd_rgd_algorithm(rng):
     X0 = rbcd.scatter_to_agents(
         lift(T0, rbcd.lifting_matrix(meta, jnp.float64)), graph)
     state = rbcd.init_state(graph, meta, X0, params=params)
-    step = lambda s, uw, rs: rbcd.rbcd_step(s, graph, meta, params,
-                                            update_weights=uw, restart=rs)
-    res = rbcd.run_rbcd(state, graph, meta, step, part, max_iters=300,
+    seg = lambda s, k, uw, rs: rbcd.rbcd_segment(
+        s, graph, k, meta, params, first_update_weights=uw,
+        first_restart=rs)
+    res = rbcd.run_rbcd(state, graph, meta, seg, part, max_iters=300,
                         grad_norm_tol=1e-2, params=params)
     assert res.cost_history[-1] < res.cost_history[0]
     assert res.grad_norm_history[-1] < 0.5 * res.grad_norm_history[0]
@@ -483,9 +484,10 @@ def _verdict_problem(rng, n=50, noise=0.05):
 
 
 def test_verdict_loop_matches_legacy_histories(rng):
-    """Full-run parity: verdict mode reproduces the per-eval loop's
-    cost/gradnorm histories bitwise, termination label, round count, and
-    (at max_iters, where there is no overshoot) the iterate itself."""
+    """Full-run parity: a coarse fetch cadence (K = 4 evals) reproduces
+    the default cadence's (K = eval_every) cost/gradnorm histories
+    bitwise, termination label, round count, and (at max_iters, where
+    there is no overshoot) the iterate itself."""
     meas = _verdict_problem(rng)
     params = AgentParams(d=3, r=5, num_robots=2, rel_change_tol=0.0)
     a = rbcd.solve_rbcd(meas, 2, params=params, max_iters=24, eval_every=2,
@@ -501,8 +503,9 @@ def test_verdict_loop_matches_legacy_histories(rng):
 
 def test_verdict_loop_termination_latches_mid_window(rng):
     """A gradnorm termination latched between verdict fetches reports the
-    same terminal eval/round as the per-eval loop — histories truncated
-    at the latched eval, not at the fetch boundary."""
+    same terminal eval/round as the default cadence (K = eval_every) —
+    histories truncated at the latched eval, not at the fetch
+    boundary."""
     meas = _verdict_problem(rng)
     params = AgentParams(d=3, r=5, num_robots=2, rel_change_tol=0.0)
     a = rbcd.solve_rbcd(meas, 2, params=params, max_iters=200, eval_every=1,
@@ -514,6 +517,29 @@ def test_verdict_loop_termination_latches_mid_window(rng):
     assert (a.iterations, a.terminated_by) == (b.iterations, b.terminated_by)
     assert a.cost_history == b.cost_history
     assert a.grad_norm_history == b.grad_norm_history
+
+
+@pytest.mark.parametrize("eval_every", [1, 2, 5])
+def test_default_cadence_is_per_eval(rng, eval_every):
+    """``verdict_every`` omitted reads the verdict word at every eval: the
+    default solve equals an explicit ``verdict_every=eval_every`` one —
+    rounds, termination label, histories, and the terminal iterate and
+    rounded trajectory bitwise — for a gate-terminated and a
+    consensus-terminated solve."""
+    meas = _verdict_problem(rng, n=40)
+    for rel_tol, term in ((1e-4, "grad_norm"), (3e-4, "consensus")):
+        params = AgentParams(d=3, r=5, num_robots=2, rel_change_tol=rel_tol)
+        kw = dict(params=params, max_iters=200, eval_every=eval_every,
+                  grad_norm_tol=2e-2, dtype=jnp.float64)
+        a = rbcd.solve_rbcd(meas, 2, **kw)
+        b = rbcd.solve_rbcd(meas, 2, verdict_every=eval_every, **kw)
+        assert a.terminated_by == term
+        assert (a.iterations, a.terminated_by) == \
+            (b.iterations, b.terminated_by)
+        assert a.cost_history == b.cost_history
+        assert a.grad_norm_history == b.grad_norm_history
+        assert np.array_equal(np.asarray(a.X), np.asarray(b.X))
+        assert np.array_equal(np.asarray(a.T), np.asarray(b.T))
 
 
 def test_verdict_loop_fetch_cadence(rng, monkeypatch):
@@ -532,6 +558,39 @@ def test_verdict_loop_fetch_cadence(rng, monkeypatch):
                           dtype=jnp.float64, verdict_every=16)
     assert res.iterations == 32
     assert count[0] == 32 // 16 + 1  # words + one fused terminal epilogue
+
+
+@pytest.mark.parametrize("telemetry", [False, True])
+def test_default_cadence_host_syncs(rng, monkeypatch, tmp_path, telemetry):
+    """``verdict_every`` omitted reads one word per eval.  Telemetry off:
+    rounds/eval_every word fetches plus ONE terminal epilogue fetch, also
+    with ``certify_mode="off"``.  Telemetry on: the lazy history fetch
+    rides every boundary too, so ``host_syncs_per_100_rounds`` reads
+    200/eval_every."""
+    from dpgo_tpu import obs
+    from dpgo_tpu.obs.events import read_events
+
+    meas = _verdict_problem(rng)
+    params = AgentParams(d=3, r=5, num_robots=2, rel_change_tol=0.0)
+    count = [0]
+    orig = rbcd._host_fetch
+    monkeypatch.setattr(rbcd, "_host_fetch",
+                        lambda x: (count.__setitem__(0, count[0] + 1),
+                                   orig(x))[1])
+    kw = dict(params=params, max_iters=32, eval_every=4, grad_norm_tol=0.0,
+              dtype=jnp.float64)
+    if not telemetry:
+        res = rbcd.solve_rbcd(meas, 2, **kw)
+        assert res.iterations == 32 and res.certificate is None
+        assert count[0] == 32 // 4 + 1  # words + one terminal epilogue
+        return
+    run_dir = str(tmp_path / "run")
+    with obs.run_scope(run_dir):
+        res = rbcd.solve_rbcd(meas, 2, **kw)
+    assert res.iterations == 32
+    syncs = [e["value"] for e in read_events(f"{run_dir}/events.jsonl")
+             if e.get("metric") == "host_syncs_per_100_rounds"]
+    assert syncs == [pytest.approx(200.0 / 4)]
 
 
 def test_verdict_every_must_divide_eval_every(rng):
@@ -558,8 +617,9 @@ def test_verdict_word_pack_unpack_roundtrip():
 
 def test_verdict_loop_gnc_weight_updates_match(rng):
     """Robust (GNC) schedule parity: flagged weight-update rounds land on
-    the same rounds in verdict mode (host-deterministic schedule_bounds),
-    so the mu trajectory and histories agree with the per-eval loop."""
+    the same rounds at K = 2 evals as at the default cadence
+    (host-deterministic schedule_bounds), so the mu trajectory and
+    histories agree."""
     from dpgo_tpu.config import RobustCostParams, RobustCostType
 
     meas = _verdict_problem(rng)

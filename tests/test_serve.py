@@ -2,6 +2,7 @@
 batched-vs-sequential parity, admission control, warm pools, SLO
 telemetry, and the zero-overhead fence."""
 
+import json
 import threading
 import time
 
@@ -183,7 +184,7 @@ def test_warm_pool_precompiles_bucket_executables():
         warm_req = _request(_problem(n=24, seed=3))
         assert srv.warm([warm_req]) == 1
         compiles_after_warm = srv.cache.compiles
-        assert compiles_after_warm >= 3  # segment + metrics + finalize
+        assert compiles_after_warm >= 3  # segment + verdict + epilogue
         res = srv.solve(_request(_problem(n=25, seed=4)), timeout=300)
         assert np.isfinite(res.cost_history[-1])
         # Same bucket, same batch width: the live request reused the
@@ -374,11 +375,11 @@ def test_submissions_from_many_threads_are_safe():
 
 
 def test_run_bucket_verdict_mode_matches_legacy():
-    """ISSUE-9 batched verdict vector: run_bucket(verdict_every=K)
-    reproduces the legacy per-eval batch's histories, per-problem
-    termination labels, and round counts — including members that latch
-    termination at different evals — with one [B] word fetch per K
-    rounds."""
+    """Batched verdict vector: a coarse cadence
+    (``verdict_every=4`` at ``eval_every=2``) reproduces the default
+    cadence's (K = eval_every) histories, per-problem termination labels,
+    and round counts — including members that latch termination at
+    different evals — with one [B] word fetch per K rounds."""
     metas = [_problem(n=24, seed=0), _problem(n=27, seed=1, num_lc=6)]
     probs = [rbcd.prepare_problem(m, 2, params=PARAMS, init=None,
                                   pallas_sel=False) for m in metas]
@@ -403,19 +404,43 @@ def test_run_bucket_verdict_mode_matches_legacy():
                    grad_norm_tol=1e-3, eval_every=3, verdict_every=4)
 
 
+def test_batched_verdict_compiles_once_across_gates():
+    """The batched verdict program takes the gradient gate as an
+    argument: two buckets at gates 1e-3 and 1e-2 share one verdict cache
+    entry (and one executable), and each gate still decides its own
+    termination."""
+    params = AgentParams(d=3, r=5, num_robots=2, rel_change_tol=0.0)
+    prob = rbcd.prepare_problem(_problem(n=24, seed=0), 2, params=params,
+                                init=None, pallas_sel=False)
+    padded = [pad_problem(prob, bucket_shape_of(prob, 64))]
+    cache = ExecutableCache()
+    gates = (1e-3, 1e-2)
+    res = [run_bucket(padded, cache, max_iters=200, grad_norm_tol=tol,
+                      eval_every=2)[0][0] for tol in gates]
+    (vex,) = [e for k, e in cache._entries.items()
+              if json.loads(k)["kind"].startswith("verdict")]
+    assert cache.compiles == len(cache) == 3  # segment, verdict, epilogue
+    assert vex._cache_size() == 1
+    for r, tol in zip(res, gates):
+        assert r.terminated_by == "grad_norm"
+        assert r.grad_norm_history[-1] < tol <= r.grad_norm_history[-2]
+    assert res[1].iterations <= res[0].iterations
+
+
 def test_server_verdict_every_plumbs_to_dispatch():
     """SolveServer(verdict_every=K) solves through the batched verdict
-    loop and returns the same result as the legacy server; a request
-    whose eval_every does not divide K falls back to the legacy loop
-    rather than erroring."""
+    loop and returns the same result as a default server; a request
+    whose eval_every does not divide K runs at K = eval_every rather
+    than erroring, and matches a default server's solve."""
     meas = _problem()
     with SolveServer(max_batch=4, verdict_every=4) as srv:
         t = srv.submit(_request(meas, eval_every=2))
         r_v = t.result(timeout=60)
-        t2 = srv.submit(_request(meas, eval_every=3))  # incompatible -> legacy
+        t2 = srv.submit(_request(meas, eval_every=3))  # runs at K = 3
         r_l = t2.result(timeout=60)
     with SolveServer(max_batch=4) as srv:
         r_ref = srv.submit(_request(meas, eval_every=2)).result(timeout=60)
+        r_ref3 = srv.submit(_request(meas, eval_every=3)).result(timeout=60)
     assert r_v.cost_history == r_ref.cost_history
-    assert np.isfinite(r_l.cost_history).all() \
-        if hasattr(np.asarray(r_l.cost_history), 'all') else True
+    assert r_l.cost_history == r_ref3.cost_history
+    assert r_l.grad_norm_history == r_ref3.grad_norm_history

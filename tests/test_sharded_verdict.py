@@ -140,9 +140,10 @@ def test_sharded_verdict_matches_single_device_verdict(rng):
 
 
 def test_sharded_verdict_matches_sharded_per_eval(rng):
-    """The sharded verdict loop vs the sharded per-eval driver on the
-    SAME mesh: identical termination and histories — the verdict-word
-    contract carries to the mesh unchanged."""
+    """The sharded loop at a coarse cadence (K = 2 evals) vs the default
+    cadence (K = eval_every) on the SAME mesh: identical termination and
+    histories — the verdict-word contract carries to the mesh
+    unchanged."""
     meas = _noisy(rng)
     params = AgentParams(d=3, r=5, num_robots=8, rel_change_tol=0.0)
     kw = dict(mesh=make_mesh(8), params=params, max_iters=40,

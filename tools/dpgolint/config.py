@@ -126,16 +126,15 @@ def project_config() -> Config:
             "DPG003": {
                 "per_file": {
                     "dpgo_tpu/models/rbcd.py": {
-                        # _run_verdict_loop is the device-resident driver
-                        # (ISSUE 9): its ONLY sanctioned in-loop fetches
-                        # are the verdict word, the lazy history, and the
-                        # terminal bookkeeping — each carries a reviewed
-                        # suppression; _host_fetch is the seam they all
-                        # route through, and any new call to it inside a
-                        # hot loop is flagged.
+                        # run_rbcd is the device-resident driver: its
+                        # ONLY sanctioned in-loop fetches are the verdict
+                        # word, the lazy history, and the terminal
+                        # epilogue — each carries a reviewed suppression;
+                        # _host_fetch is the seam they all route through,
+                        # and any new call to it inside a hot loop is
+                        # flagged.
                         "hot_functions": ["run_rbcd", "dispatch_prepared",
-                                          "solve_rbcd",
-                                          "_run_verdict_loop"],
+                                          "solve_rbcd"],
                         "sync_calls": ["_host_fetch"],
                     },
                     "dpgo_tpu/serve/runner.py": {

@@ -18,8 +18,8 @@ functions:
   the verdict-word loop), so wrapping a transfer in the seam helper does
   not hide it from the rule.
 
-The sanctioned readback seams (the per-eval stacked fetch, the verdict-
-word/lazy-history fetches) carry reviewed ``# dpgolint: disable=DPG003``
+The sanctioned readback seams (the verdict-word, lazy-history and
+terminal-epilogue fetches) carry reviewed ``# dpgolint: disable=DPG003``
 suppressions; anything else is a hot-loop regression.
 """
 
@@ -96,8 +96,8 @@ class HostSyncRule(Rule):
                         module, node,
                         f"{hit} inside the {fn.name!r} hot loop — implicit "
                         "device->host transfer; batch it into the "
-                        "per-eval stacked readback or add a reviewed "
-                        "suppression at a sanctioned seam"))
+                        "verdict program's device-side history or add a "
+                        "reviewed suppression at a sanctioned seam"))
         return out
 
     def _classify(self, call: ast.Call, np_names: set[str],
