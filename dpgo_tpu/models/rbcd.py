@@ -1309,15 +1309,29 @@ def refresh_problem(state: RBCDState, graph: MultiAgentGraph, meta: GraphMeta,
     return state._replace(chol=chol, Qbuf=qbuf)
 
 
+def _lift_scatter(T0: jax.Array, graph: MultiAgentGraph,
+                  meta: GraphMeta) -> jax.Array:
+    """T0 [n, d, d+1] -> lifted per-agent blocks [A, n_max, r, d+1]."""
+    X0g = lift(T0, lifting_matrix(meta, T0.dtype))
+    return scatter_to_agents(X0g, graph)
+
+
 @partial(jax.jit, static_argnames=("meta", "n", "init_fn"))
 def _global_init_jit(edges_g: EdgeSet, graph: MultiAgentGraph,
                      meta: GraphMeta, n: int, init_fn) -> jax.Array:
     """Shared body of the centralized init policies: build T0 [n, d, d+1]
     with ``init_fn(edges, n)`` (a module-level function, so the static
     hash is stable), lift, scatter to agents."""
-    T0 = init_fn(edges_g, n)
-    X0g = lift(T0, lifting_matrix(meta, T0.dtype))
-    return scatter_to_agents(X0g, graph)
+    return _lift_scatter(init_fn(edges_g, n), graph, meta)
+
+
+@partial(jax.jit, static_argnames=("meta", "dtype"))
+def _lift_scatter_jit(T0, graph: MultiAgentGraph, meta: GraphMeta,
+                      dtype) -> jax.Array:
+    """Cast a host-computed T0 to ``dtype``, lift, scatter to agents: one
+    program keyed on shapes, so a new problem of the same shape compiles
+    nothing."""
+    return _lift_scatter(T0.astype(dtype), graph, meta)
 
 
 def lifted_init(edges_g: EdgeSet, graph: MultiAgentGraph, meta: GraphMeta,
@@ -1346,11 +1360,37 @@ def centralized_chordal_init(part: Partition, meta: GraphMeta, graph: MultiAgent
     """Centralized chordal init, lifted and scattered to agents — the demo
     initialization of ``MultiRobotExample.cpp:158-165``.
 
-    One jitted program: run eagerly, the chordal CG solves alone dispatch
-    thousands of individual device ops, each a host dispatch."""
-    edges_g = edge_set_from_measurements(part.meas_global, dtype=dtype)
-    return lifted_init(edges_g, graph, meta, part.meas_global.num_poses,
-                       "chordal")
+    The two least-squares solves run on the host in float64, one sparse
+    factorization each (``chordal.chordal_initialization_host``, span
+    ``rbcd.init.factor``); one program keyed on shapes then casts, lifts
+    and scatters.  The device CG (``chordal.chordal_initialization`` in
+    one program) runs instead where the certificate's fill guard trips on
+    the global edges (``certify._expander_guard``, ``reason="guarded"``)
+    or the pinned system is singular (``reason="singular"``).  With a
+    telemetry run, ``chordal_init_total{path=host_factor|device_cg,
+    reason}`` counts which path each init took."""
+    from . import certify as certify_mod
+
+    meas = part.meas_global
+    n = meas.num_poses
+    edges_g = edge_set_from_measurements(meas, dtype=dtype, as_numpy=True)
+    T0, path, reason = None, "device_cg", "guarded"
+    if not certify_mod._expander_guard(edges_g, n, meas.d):
+        try:
+            with trace.span("rbcd.init.factor"):
+                T0 = chordal.chordal_initialization_host(meas)
+            path, reason = "host_factor", "factored"
+        except chordal.SingularChordalSystem:
+            reason = "singular"
+    run = obs.get_run()
+    if run is not None:
+        run.counter("chordal_init_total",
+                    "centralized chordal inits by the path that solved "
+                    "them").inc(path=path, reason=reason)
+    if T0 is None:
+        return _global_init_jit(edges_g, graph, meta, n,
+                                chordal.chordal_initialization)
+    return _lift_scatter_jit(T0, graph, meta, dtype)
 
 
 def centralized_odometry_init(part: Partition, meta: GraphMeta,
@@ -2390,7 +2430,9 @@ def prepare_problem(
             graph, meta = build_graph(part, params.r, dtype,
                                       pallas_sel=pallas_sel,
                                       sel_mode=resolved_sel_mode(params))
-        # Host dispatch only: the init's device work runs on past it.
+        # The chordal init's factorizations are host work inside this span;
+        # a device init (odometry, distributed, the CG fallback) only
+        # dispatches here and runs on past it.
         with trace.span("rbcd.init"):
             X0 = initial_state_for(init, part, meta, graph, params, dtype) \
                 if init is not None else None

@@ -41,8 +41,10 @@ robustness change reports through:
   profiler trace's host plane, on the device ops' clock), and every
   ended span observes ``span_seconds{span=<name>}``.  The solve path's
   spans: ``rbcd.prepare`` (``rbcd.partition``, ``rbcd.build_graph``,
-  ``rbcd.init``), ``rbcd.dispatch`` (``rbcd.programs``,
-  ``rbcd.enqueue``, ``rbcd.fetch{what=word|history|epilogue|eval}``,
+  ``rbcd.init`` > ``rbcd.init.factor``, the host chordal factorization;
+  counter ``chordal_init_total{path, reason}``), ``rbcd.dispatch``
+  (``rbcd.programs``, ``rbcd.enqueue``,
+  ``rbcd.fetch{what=word|history|epilogue|eval}``,
   ``cert.decide``), and inside the certificate ``cert.f64``,
   ``cert.f64.assemble``, ``cert.f64.sa``, ``cert.f64.lobpcg``,
   ``cert.f64.shift_invert`` (> ``cert.f64.inertia``, the symmetric

@@ -95,6 +95,19 @@ def rotation2d(theta) -> np.ndarray:
     return R.reshape(theta.shape + (2, 2))
 
 
+def project_to_rotation_np(M: np.ndarray) -> np.ndarray:
+    """Project [..., d, d] matrices onto SO(d) in numpy (SVD with
+    determinant fix).
+
+    Pure host work on purpose: ``project_to_rotation`` would dispatch one
+    tiny kernel per call to the default backend — a host dispatch each,
+    which turns a 100k-pose synthesis into minutes."""
+    U, _, Vh = np.linalg.svd(M)
+    det = np.linalg.det(U @ Vh)
+    U[det < 0, :, -1] *= -1.0
+    return U @ Vh
+
+
 def _project_to_rotation_batch(M: jax.Array) -> jax.Array:
     U, _, Vh = jnp.linalg.svd(M, full_matrices=False)
     det = jnp.linalg.det(U @ Vh)

@@ -12,25 +12,13 @@ from dpgo_tpu.types import Measurements
 from dpgo_tpu.utils import lie
 
 
-def _project_rotations_np(M: np.ndarray) -> np.ndarray:
-    """Batched numpy SO(d) projection (SVD with det fix).
-
-    Pure host work on purpose: the JAX equivalent (``lie.project_to_rotation``)
-    would dispatch one tiny kernel per call to the *default* backend — a
-    host dispatch each, which turns a 100k-pose synthesis into minutes."""
-    U, _, Vh = np.linalg.svd(M)
-    det = np.linalg.det(U @ Vh)
-    U[det < 0, :, -1] *= -1.0
-    return U @ Vh
-
-
 def random_rotation(rng, d=3):
-    return _project_rotations_np(rng.standard_normal((d, d))[None])[0]
+    return lie.project_to_rotation_np(rng.standard_normal((d, d))[None])[0]
 
 
 def random_trajectory(rng, n, d=3, step=1.0):
     """Ground-truth poses: random rotations, random-walk translations."""
-    Rs = _project_rotations_np(rng.standard_normal((n, d, d)))
+    Rs = lie.project_to_rotation_np(rng.standard_normal((n, d, d)))
     ts = np.cumsum(step * rng.standard_normal((n, d)), axis=0)
     # Anchor pose 0 at the identity for easy gauge comparison.
     R0inv = Rs[0].T
@@ -256,7 +244,7 @@ def corrupt_loop_closures(meas: Measurements, fraction: float, rng=None,
     out = meas.select(np.arange(len(meas)))  # fancy indexing copies every field
     out.weight = np.ones(len(meas))
     if k:
-        out.R[outlier_idx] = _project_rotations_np(
+        out.R[outlier_idx] = lie.project_to_rotation_np(
             rng.standard_normal((k, d, d)))
         # Translation scale from the data itself: outlier norms uniform in
         # [0, 2 * the 95th-percentile measured translation norm].
@@ -377,7 +365,7 @@ def corrupt_loop_closures_correlated(
             Rm = Rs[ia].T @ Rb
             tm = Rs[ia].T @ (tb - ts[ia])
             # Small in-cluster noise so edges corroborate, not duplicate.
-            Rm = _project_rotations_np(
+            Rm = lie.project_to_rotation_np(
                 (Rm + rot_noise * rng.standard_normal((d, d)))[None])[0]
             tm = tm + trans_noise * rng.standard_normal(d)
             out.p1[row], out.p2[row] = ia, ib  # r1/r2 stay 0 (global ids)

@@ -95,6 +95,37 @@ def test_new_pose_count_compiles_its_own_programs(three_solves):
     assert any("_epilogue_jit" in p for p in grown), grown
 
 
+def test_every_solve_inits_by_host_factor(three_solves):
+    """In the certified cell's settings each solve's chordal init is
+    factored on the host: one ``host_factor`` count per solve, no device
+    CG."""
+    for k, snap in enumerate(three_solves, start=1):
+        assert _total(snap, "chordal_init_total", path="host_factor") == k
+        assert _total(snap, "chordal_init_total", path="device_cg") == 0
+
+
+def test_same_shape_init_builds_no_program(tmp_path):
+    """``prepare_problem(init="chordal")`` builds one program, the cast,
+    lift and scatter keyed on shapes; a second noise draw of the graph
+    builds none."""
+    obs.end_run()
+    meas = _city(184, seed=11)
+    params = AgentParams(d=2, r=3, num_robots=2)
+    with obs.run_scope(str(tmp_path / "run")) as run:
+        built = []
+        for m in (meas, _redraw(meas, 5)):
+            before = run.registry.snapshot()
+            rbcd.prepare_problem(m, 2, params=params, dtype=jnp.float32,
+                                 init="chordal")
+            after = run.registry.snapshot()
+            built.append({p for p in _programs(after)
+                          if _total(after, "jax_programs_total", program=p)
+                          > _total(before, "jax_programs_total", program=p)})
+    assert any("_lift_scatter_jit" in p for p in built[0]), built[0]
+    assert not any("_global_init_jit" in p for p in built[0]), built[0]
+    assert built[1] == set()
+
+
 @pytest.mark.parametrize("path", ["closure", "batched"])
 def test_verdict_update_shared_by_both_paths(monkeypatch, path):
     """The single-device program, the ``metrics_body=`` closure and the
